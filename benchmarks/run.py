@@ -36,6 +36,8 @@ def main() -> None:
                          "cache (pre-warms it for later runs)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.tune:
         from repro.kernels import autotune
         autotune.enable(True)

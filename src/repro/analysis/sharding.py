@@ -60,9 +60,8 @@ def _rule_sets():
 
 
 def _meshes():
-    import jax
-    from ..launch.mesh import MESH_AXIS_LAYOUTS
-    return [(ax, jax.make_mesh((1,) * len(ax), ax))
+    from ..launch.mesh import MESH_AXIS_LAYOUTS, make_mesh
+    return [(ax, make_mesh((1,) * len(ax), ax))
             for ax in MESH_AXIS_LAYOUTS]
 
 

@@ -62,10 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                   # jax >= 0.6
-    _shard_map = jax.shard_map
-except AttributeError:                 # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
+_shard_map = jax.shard_map
 
 from ..common import act_fn, cdiv, round_up
 from ..configs.base import FFNConfig
@@ -197,28 +194,33 @@ def _sort_path(params: Dict, xf: jax.Array, cfg: FFNConfig,
     grouped matmul where row-groups share an expert matrix; 4. scatter-add
     results back per token, weighted by the gates.
 
-    Under an active mesh the whole pipeline is pinned to REPLICATED: the
-    grouped GEMMs here are not GSPMD-partitionable — ``jax.lax.ragged_dot``
-    with expert-sharded weights silently returns wrong values (observed on
-    jax 0.4.37: the partitioner slices the group dim without reconciling
-    group_sizes), and the pallas custom calls can't be partitioned either.
-    The sort path is the single-shard rung of the capability chain;
-    "einsum" (GSPMD) and "shard_map" (explicit EP) are the sharded
-    dispatches.
+    Under an active mesh the whole pipeline runs REPLICATED inside a
+    ``shard_map`` (every device computes the full layer on gathered inputs):
+    GSPMD cannot partition the Mosaic kernels at all. The sort path is the
+    single-shard rung of the capability chain; "einsum" (GSPMD) and
+    "shard_map" (explicit EP) are the sharded dispatches.
     """
-    from ..kernels import ops as kops  # local import: kernels optional at import
-
     mesh = current_mesh()
-    if mesh is not None:
-        from jax.sharding import NamedSharding
-        rep = NamedSharding(mesh, P())
-        xf = jax.lax.with_sharding_constraint(xf, rep)
-        info = info._replace(
-            idx=jax.lax.with_sharding_constraint(info.idx, rep),
-            gates=jax.lax.with_sharding_constraint(info.gates, rep))
-        params = {name: (jax.lax.with_sharding_constraint(v, rep)
-                         if name in ("we1", "we1g", "we2") else v)
-                  for name, v in params.items()}
+    if mesh is None:
+        return _sort_local(params, xf, cfg, info, e)
+    names = [name for name in ("we1", "we1g", "we2") if name in params]
+
+    def local(xf, idx, gates, *weights):
+        sel = SelectionInfo(probs=None, sel=None, idx=idx, gates=gates)
+        return _sort_local(dict(zip(names, weights)), xf, cfg, sel, e)
+
+    # check_vma=False: the kernels' outputs carry no varying-axes type. The
+    # transpose then scales replicated cotangents by 1/devices and psums
+    # them back, so gradients stay exact.
+    return _shard_map(local, mesh=mesh, in_specs=P(), out_specs=P(),
+                      check_vma=False)(
+        xf, info.idx, info.gates, *(params[name] for name in names))
+
+
+def _sort_local(params: Dict, xf: jax.Array, cfg: FFNConfig,
+                info: SelectionInfo, e: int) -> jax.Array:
+    """``_sort_path`` on one device's (full) arrays."""
+    from ..kernels import ops as kops  # local import: kernels optional at import
 
     n, d = xf.shape
     k = cfg.k

@@ -1,3 +1,3 @@
-from . import autotune, compat, ops, ref
+from . import autotune, ops, ref
 
-__all__ = ["autotune", "compat", "ops", "ref"]
+__all__ = ["autotune", "ops", "ref"]

@@ -145,7 +145,9 @@ def active_backend() -> str:
 
 
 def active_hardware() -> Hardware:
-    return hardware_for(active_backend())
+    import jax
+    dev = jax.devices()[0]
+    return hardware_for(dev.platform, dev.device_kind)
 
 
 def default_vmem_budget(hw: Optional[Hardware] = None) -> int:

@@ -43,10 +43,13 @@ Run-batched row-DMA pipeline (shared by every streamed kernel below)
   contiguous tile (K=1, skewed routing) is 1 descriptor instead of 128; the
   worst case (no two sources adjacent) degrades to the old per-row count.
   Slack slots belong to no chunk and keep the zero fill before the DMAs.
+  The streamed operand reaches the kernel in the ``(N, P, K/P)`` row view
+  (``_row_view``): on TPU a chunk may then start at any row, which a 2-D
+  (N, K) array's (8, 128) tiling would forbid.
 
 Fused/streamed pipeline (one HBM round-trip per matmul, nothing else)
   cvmm_fused_w1_pallas   gather + GEMM + activation(/GLU) epilogue. The
-      unsorted activations stay in HBM (``pltpu.ANY`` memory space) — the
+      unsorted activations stay in HBM (``pl.ANY`` memory space) — the
       kernel never requires whole-array VMEM residency, so it scales to
       production token counts. The chunk table is scalar-prefetched and
       drives a double-buffered DMA pipeline: on the first N-tile of row tile
@@ -142,7 +145,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ..common import act_fn
 from . import autotune
 from .autotune import LANE, TM
-from .compat import tpu_compiler_params
 
 # Per-kernel VMEM working-set budget. Derived from the active Hardware model
 # (0.75 * vmem_bytes = 12 MiB on the TPU model; $REPRO_VMEM_BUDGET overrides)
@@ -264,7 +266,7 @@ def cvmm_pallas(x_pad: jax.Array, tile_expert: jax.Array, w: jax.Array,
             out_specs=pl.BlockSpec((TM, tn), lambda i, j, te: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), x_pad.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(tile_expert, x_pad, w)
@@ -320,7 +322,7 @@ def cvmm_dw_pallas(x_pad: jax.Array, tile_expert: jax.Array, g_pad: jax.Array,
             out_specs=pl.BlockSpec((1, tk, tn), lambda k, n, m, te: (te[m], k, n)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_experts, k_pad, n_pad), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(tile_expert, x_pad, g_pad)
@@ -384,6 +386,49 @@ def stream_schedule_step(i, m_tiles: int, n_buffers: int, *, issue, wait,
     return stream_slot(i, n_buffers)
 
 
+def _row_pack(dtype) -> int:
+    """Elements of ``dtype`` packed into one 32-bit sublane word (1 for f32,
+    2 for bf16)."""
+    return max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _row_view(x: jax.Array) -> jax.Array:
+    """(N, K) -> (N, P, K/P) with P = ``_row_pack``: the streamed operand's HBM
+    view. A 2-D array tiles its rows in groups of 8 (16 for bf16), and Mosaic
+    refuses a row DMA whose start it cannot prove aligned to that tiling. In
+    this view rows are an untiled leading dim and each row is exactly one
+    (P, K/P) packed sublane row, so XLA lays it out with no padding
+    (T(1,128) in f32, T(2,128)(2,1) in bf16) and a DMA may start at any row."""
+    n, k = x.shape
+    p = _row_pack(x.dtype)
+    return x.reshape(n, p, k // p)
+
+
+def _stream_scratch(n_buffers: int, k_pad: int, dtype):
+    """VMEM slots of the streamed pipeline, in the row view's layout."""
+    p = _row_pack(dtype)
+    return [pltpu.VMEM((n_buffers, TM, p, k_pad // p), dtype),
+            pltpu.SemaphoreType.DMA((n_buffers,))]
+
+
+def _slot_tile(xs_ref, slot):
+    """The (TM, K) row tile held in pipeline slot ``slot``."""
+    _, tm, p, kp = xs_ref.shape
+    return xs_ref[slot].reshape(tm, p * kp)
+
+
+def _gate_view(gate_tiles: jax.Array) -> jax.Array:
+    """(n_tiles, TM) -> (n_tiles, 1, TM): a (1, TM) block of a 2-D array is
+    illegal on TPU (a second-minor block dim must be a multiple of 8 or the
+    whole dim); the 3-D view is a free bitcast and its (1, 1, TM) block is."""
+    return gate_tiles.reshape(gate_tiles.shape[0], 1, gate_tiles.shape[1])
+
+
+def _gate_column(gate_ref):
+    """The (1, 1, TM) gate block as a (TM, 1) per-row column."""
+    return gate_ref[0].reshape(TM, 1)
+
+
 def _run_dmas(t, row_src_ref, run_start_ref, run_off_ref, x_hbm, xs_ref,
               sem_ref, slot, *, wait: bool):
     """Issue (or wait for) the run-batched DMA chunks of row tile ``t``.
@@ -411,8 +456,10 @@ def _run_dmas(t, row_src_ref, run_start_ref, run_off_ref, x_hbm, xs_ref,
         def body(j, _, s=s):
             off = run_start_ref[t * TM + j]
             src = row_src_ref[t * TM + off]
-            cp = pltpu.make_async_copy(x_hbm.at[pl.ds(src, s), :],
-                                       xs_ref.at[slot, pl.ds(off, s), :],
+            # Rows index the untiled leading dim of the row view, so neither
+            # offset has to be a multiple of the sublane tiling.
+            cp = pltpu.make_async_copy(x_hbm.at[pl.ds(src, s)],
+                                       xs_ref.at[slot, pl.ds(off, s)],
                                        sem_ref.at[slot])
             cp.wait() if wait else cp.start()
             return 0
@@ -480,7 +527,7 @@ def _fused_w1_body(row_src_ref, run_start_ref, run_off_ref, x_hbm, w1_ref,
     def _():
         _stream_tile(i, row_src_ref, run_start_ref, run_off_ref, x_hbm,
                      xs_ref, sem_ref, n_buffers=n_buffers)
-    xt = xs_ref[stream_slot(i, n_buffers)]
+    xt = _slot_tile(xs_ref, stream_slot(i, n_buffers))
     h = jnp.dot(xt, w1_ref[0], preferred_element_type=jnp.float32)
     u = act_fn(act_name)(h)
     if w1g_ref is not None:
@@ -520,7 +567,7 @@ def cvmm_fused_w1_pallas(x: jax.Array, row_src: jax.Array,
                          n_buffers: int | None = None):
     """Streamed gather-fused grouped GEMM with activation(/GLU) epilogue.
 
-    x (N_rows, K_pad) — the UNSORTED activations, left in HBM (``pltpu.ANY``)
+    x (N_rows, K_pad) — the UNSORTED activations, left in HBM (``pl.ANY``)
     and streamed through the run-batched double-buffered async-copy pipeline
     (see ``_stream_tile``); the row count is unconstrained — no multiple-of-8
     padding, no whole-array VMEM residency. row_src (M_pad,) int32 maps padded
@@ -561,8 +608,8 @@ def cvmm_fused_w1_pallas(x: jax.Array, row_src: jax.Array,
                           lambda i, j, rs, rst, rl, te: (te[i], 0, j))
     o_spec = pl.BlockSpec((TM, tn), lambda i, j, rs, rst, rl, te: (i, j))
     o_shape = jax.ShapeDtypeStruct((m_pad, g_pad), x.dtype)
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY), w_spec]
-    operands = [row_src, run_start, run_off, tile_expert, x, w1]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY), w_spec]
+    operands = [row_src, run_start, run_off, tile_expert, _row_view(x), w1]
     if w1g is not None:
         in_specs.append(w_spec)
         operands.append(w1g)
@@ -578,11 +625,10 @@ def cvmm_fused_w1_pallas(x: jax.Array, row_src: jax.Array,
             grid=grid,
             in_specs=in_specs,
             out_specs=[o_spec] * n_out,
-            scratch_shapes=[pltpu.VMEM((n_buffers, TM, k_pad), x.dtype),
-                            pltpu.SemaphoreType.DMA((n_buffers,))],
+            scratch_shapes=_stream_scratch(n_buffers, k_pad, x.dtype),
         ),
         out_shape=[o_shape] * n_out,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -594,7 +640,7 @@ def _gather_rows_kernel(row_src_ref, run_start_ref, run_off_ref, x_hbm, o_ref,
     i = pl.program_id(0)
     slot = _stream_tile(i, row_src_ref, run_start_ref, run_off_ref, x_hbm,
                         xs_ref, sem_ref, n_buffers=n_buffers)
-    o_ref[...] = xs_ref[slot]
+    o_ref[...] = _slot_tile(xs_ref, slot)
 
 
 def _gather_rows_weighted_kernel(row_src_ref, run_start_ref, run_off_ref,
@@ -603,8 +649,8 @@ def _gather_rows_weighted_kernel(row_src_ref, run_start_ref, run_off_ref,
     i = pl.program_id(0)
     slot = _stream_tile(i, row_src_ref, run_start_ref, run_off_ref, x_hbm,
                         xs_ref, sem_ref, n_buffers=n_buffers)
-    o_ref[...] = (xs_ref[slot].astype(jnp.float32)
-                  * w_ref[0][:, None]).astype(o_ref.dtype)
+    o_ref[...] = (_slot_tile(xs_ref, slot).astype(jnp.float32)
+                  * _gate_column(w_ref)).astype(o_ref.dtype)
 
 
 def gather_tile_fits(k_pad: int, bytes_per_el: int,
@@ -642,16 +688,17 @@ def cvmm_gather_rows_pallas(x: jax.Array, row_src: jax.Array,
         raise ValueError(
             f"streamed gather tile working set exceeds VMEM budget for "
             f"K_pad={k_pad}; gate calls with ops.gather_supported")
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]
-    operands = [row_src, run_start, run_off, x]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [row_src, run_start, run_off, _row_view(x)]
     if weight_tiles is None:
         kernel = _gather_rows_kernel
         out_spec = pl.BlockSpec((TM, k_pad), lambda i, rs, rst, rl: (i, 0))
     else:
         assert weight_tiles.shape == (m_pad // TM, TM)
         kernel = _gather_rows_weighted_kernel
-        in_specs.append(pl.BlockSpec((1, TM), lambda i, rs, rst, rl: (i, 0)))
-        operands.append(weight_tiles)
+        in_specs.append(pl.BlockSpec((1, 1, TM),
+                                     lambda i, rs, rst, rl: (i, 0, 0)))
+        operands.append(_gate_view(weight_tiles))
         out_spec = pl.BlockSpec((TM, k_pad), lambda i, rs, rst, rl: (i, 0))
     return pl.pallas_call(
         functools.partial(kernel, n_buffers=n_buffers),
@@ -660,11 +707,10 @@ def cvmm_gather_rows_pallas(x: jax.Array, row_src: jax.Array,
             grid=(m_pad // TM,),
             in_specs=in_specs,
             out_specs=out_spec,
-            scratch_shapes=[pltpu.VMEM((n_buffers, TM, k_pad), x.dtype),
-                            pltpu.SemaphoreType.DMA((n_buffers,))],
+            scratch_shapes=_stream_scratch(n_buffers, k_pad, x.dtype),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, k_pad), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
@@ -696,7 +742,7 @@ def _dw_stream_x_kernel(rs, rst, rl, te, x_hbm, g_ref, o_ref, xs_ref, sem_ref,
     m = pl.program_id(1)
     slot = _stream_tile(m, rs, rst, rl, x_hbm, xs_ref, sem_ref, axis=1,
                         n_buffers=n_buffers)
-    acc = jax.lax.dot_general(xs_ref[slot], g_ref[...],
+    acc = jax.lax.dot_general(_slot_tile(xs_ref, slot), g_ref[...],
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (K, tb)
     _dw_accumulate(o_ref, acc, _dw_first(te, m))
@@ -707,9 +753,9 @@ def _dw_stream_g_body(rs, rst, rl, g_hbm, x_ref, gate_ref, o_ref, gs_ref,
     m = pl.program_id(1)
     slot = _stream_tile(m, rs, rst, rl, g_hbm, gs_ref, sem_ref, axis=1,
                         n_buffers=n_buffers)
-    gt = gs_ref[slot]
+    gt = _slot_tile(gs_ref, slot)
     if gate_ref is not None:
-        gt = (gt.astype(jnp.float32) * gate_ref[0][:, None]).astype(gt.dtype)
+        gt = (gt.astype(jnp.float32) * _gate_column(gate_ref)).astype(gt.dtype)
     acc = jax.lax.dot_general(x_ref[...], gt, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (tb, N)
     _dw_accumulate(o_ref, acc, _dw_first(te, m))
@@ -738,7 +784,7 @@ def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
     """dW (E, K_pad, N_pad) float32 with ONE operand streamed from unsorted HBM.
 
     stream_x=True : ``x`` is the UNSORTED (N_rows, K_pad) activations, left in
-        HBM (``pltpu.ANY``) and gathered tile-by-tile through the run-batched
+        HBM (``pl.ANY``) and gathered tile-by-tile through the run-batched
         DMA pipeline; ``g`` (M_pad, N_pad) is tile-aligned and blocked
         normally. (Backward's dW1/dW1g: activations never re-materialize.)
     stream_x=False: ``g`` is the UNSORTED (N_rows, N_pad) cotangent in HBM;
@@ -774,24 +820,24 @@ def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
             f"W_stream={stream_w}; gate calls with ops.fused_supported")
     n_buffers = N_BUFFERS if n_buffers is None else n_buffers
     grid = (block_w // tb, m_pad // TM)
-    scratch = [pltpu.VMEM((n_buffers, TM, stream_w), sdtype),
-               pltpu.SemaphoreType.DMA((n_buffers,))]
+    scratch = _stream_scratch(n_buffers, stream_w, sdtype)
     blk_spec = pl.BlockSpec((TM, tb), lambda b, m, *s: (m, b))
     if stream_x:
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY), blk_spec]
-        operands = [row_src, run_start, run_off, tile_expert, x, g]
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY), blk_spec]
+        operands = [row_src, run_start, run_off, tile_expert, _row_view(x), g]
         out_spec = pl.BlockSpec(
             (1, k_pad, tb), lambda b, m, rs, rst, rl, te: (te[m], 0, b))
         kernel = _dw_stream_x_kernel
     else:
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY), blk_spec]
-        operands = [row_src, run_start, run_off, tile_expert, g, x]
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY), blk_spec]
+        operands = [row_src, run_start, run_off, tile_expert, _row_view(g), x]
         out_spec = pl.BlockSpec(
             (1, tb, n_pad), lambda b, m, rs, rst, rl, te: (te[m], b, 0))
         if gate_tiles is not None:
             assert gate_tiles.shape == (m_pad // TM, TM)
-            in_specs.append(pl.BlockSpec((1, TM), lambda b, m, *s: (m, 0)))
-            operands.append(gate_tiles)
+            in_specs.append(pl.BlockSpec((1, 1, TM),
+                                         lambda b, m, *s: (m, 0, 0)))
+            operands.append(_gate_view(gate_tiles))
             kernel = _dw_stream_g_gate_kernel
         else:
             kernel = _dw_stream_g_kernel
@@ -806,7 +852,7 @@ def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((n_experts, k_pad, n_pad), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -814,7 +860,7 @@ def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
 
 def _fused_w2_kernel(tile_expert_ref, u_ref, w2_ref, gate_ref, o_ref):
     acc = jnp.dot(u_ref[...], w2_ref[0], preferred_element_type=jnp.float32)
-    o_ref[...] = (acc * gate_ref[0][:, None]).astype(o_ref.dtype)
+    o_ref[...] = (acc * _gate_column(gate_ref)).astype(o_ref.dtype)
 
 
 def cvmm_fused_w2_pallas(u_pad: jax.Array, tile_expert: jax.Array,
@@ -843,12 +889,12 @@ def cvmm_fused_w2_pallas(u_pad: jax.Array, tile_expert: jax.Array,
             in_specs=[
                 pl.BlockSpec((TM, g_pad), lambda i, j, te: (i, 0)),
                 pl.BlockSpec((1, g_pad, tn), lambda i, j, te: (te[i], 0, j)),
-                pl.BlockSpec((1, TM), lambda i, j, te: (i, 0)),
+                pl.BlockSpec((1, 1, TM), lambda i, j, te: (i, 0, 0)),
             ],
             out_specs=pl.BlockSpec((TM, tn), lambda i, j, te: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), u_pad.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tile_expert, u_pad, w2, gate_tiles)
+    )(tile_expert, u_pad, w2, _gate_view(gate_tiles))

@@ -16,8 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 BQ = 128
 BK = 128
@@ -92,7 +91,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, BQ, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * kvh * grp, sq_p, d), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qf[:, None].reshape(b * kvh * grp, sq_p, d), kf, vf)

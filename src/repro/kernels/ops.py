@@ -409,7 +409,7 @@ def gathered_weighted_sum(values: jax.Array, plan: GatherPlan, n_tokens: int,
 
     The framework's shared retrieval+aggregation primitive executed through
     the streamed row-DMA pipeline: the value table stays unsorted in HBM
-    (``pltpu.ANY``) and double-buffers (TM, d) row tiles through VMEM, so no
+    (``pl.ANY``) and double-buffers (TM, d) row tiles through VMEM, so no
     (N, S, d) dense value gather is ever materialized at the XLA level. PKM
     value aggregation (V = the (n_values, d) value table, S = H*K) and the
     top-K MLP's sparse down-projection (V = W2 rows, S = K) both lower here
@@ -895,7 +895,7 @@ def _fused_bwd(static, res, dy):
         (dh,) = eltwise_vjp(du)
 
     # dW2 streams dy (g-operand) and fuses the gate multiply; dW1/dW1g stream
-    # the activations (x-operand). Both pull straight from pltpu.ANY HBM.
+    # the activations (x-operand). Both pull straight from pl.ANY HBM.
     dw2 = _mask_empty(
         cvmm_dw_streamed_pallas(u, dy_e, *runs, e, stream_x=False,
                                 gate_tiles=plan.gate_tiles,

@@ -8,6 +8,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 # Every mesh axis layout this repo constructs (production, local, tests). The
 # sharding-table analyzer (repro.analysis.sharding) sweeps PARAM_AXES x rule
@@ -35,17 +36,21 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = math.prod(shape)
     devs = jax.devices()
     if len(devs) == n:
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     if len(devs) < n:
         raise RuntimeError(f"need {n} devices for mesh {shape}, have {len(devs)} "
                            "(dry-run must set xla_force_host_platform_device_count)")
     import numpy as np
     from jax.sharding import Mesh
-    return Mesh(np.asarray(devs[:n]).reshape(shape), axes)
+    return Mesh(np.asarray(devs[:n]).reshape(shape), axes,
+                axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto: the sharding rules here are
+    GSPMD constraints, and Explicit axes (jax.make_mesh's default) reject the
+    gathers and constraints the models use."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model: int = 1, pod: int = 1):
@@ -68,5 +73,5 @@ def make_local_mesh(model: int = 1, pod: int = 1):
             f"sizes whose product divides {n} (divisors: {divisors}).")
     data = n // (model * pod)
     if pod > 1:
-        return jax.make_mesh((pod, data, model), MESH_AXIS_LAYOUTS[1])
-    return jax.make_mesh((data, model), MESH_AXIS_LAYOUTS[0])
+        return make_mesh((pod, data, model), MESH_AXIS_LAYOUTS[1])
+    return make_mesh((data, model), MESH_AXIS_LAYOUTS[0])

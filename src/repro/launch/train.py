@@ -12,33 +12,36 @@ Fault-tolerance posture (exercised by tests/test_fault_tolerance.py):
     checkpoint (restart-in-place) before re-raising persistent ones;
   * straggler monitor flags slow steps for the orchestrator.
 
-XLA flags for compute/comm overlap on TPU are set by `tpu_perf_flags()` -- latency
-hiding scheduler + async collectives (a no-op on CPU).
+``run(argv)`` is the same launcher as a library call: it returns the per-step
+metrics, the compiled step and the final state (chip_smoke.py checks them).
 """
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
+from typing import Any, Dict, List, NamedTuple
 
 
-def tpu_perf_flags() -> str:
-    return " ".join([
-        "--xla_tpu_enable_latency_hiding_scheduler=true",
-        "--xla_tpu_megacore_fusion_allow_ags=true",
-        "--xla_enable_async_all_gather=true",
-        "--xla_enable_async_collective_permute=true",
-        "--xla_tpu_enable_async_collective_fusion=true",
-        "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-    ])
+class TrainRun(NamedTuple):
+    history: List[Dict[str, float]]   # per step run: step, loss, grad_norm, lr
+    compiled: Any                     # the compiled train step
+    compile_s: float                  # lower + compile wall seconds
+    state: Any                        # final train state (on device)
 
 
 def main(argv=None) -> int:
+    run(argv)
+    return 0
+
+
+def run(argv=None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="wt103-47m-moe")
     ap.add_argument("--ffn", default=None,
                     help="swap FFN kind (sigma_moe|topk|pkm|dense)")
+    ap.add_argument("--impl", default="auto",
+                    help="FFN kernel lowering (FFNConfig.impl), e.g. ragged")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -61,13 +64,11 @@ def main(argv=None) -> int:
                     help="TESTING: raise at this step to exercise restart")
     args = ap.parse_args(argv)
 
-    if "tpu" in os.environ.get("JAX_PLATFORMS", ""):
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " +
-                                   tpu_perf_flags())
+    import dataclasses
 
     import jax
     import jax.numpy as jnp
-    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..checkpoint import CheckpointManager
     from ..configs import OptimizerConfig, get_config, reduced
@@ -76,13 +77,16 @@ def main(argv=None) -> int:
     from ..runtime.monitor import StragglerMonitor
     from ..runtime.steps import init_train_state, make_train_step
     from ..sharding import TRAIN_RULES, mesh_context, tree_shardings
+    from .compile_cache import use_compile_cache
     from .mesh import make_mesh
 
+    use_compile_cache()
     dshape = tuple(int(x) for x in args.mesh.split("x"))
     mesh = make_mesh(dshape, ("data", "model")[: len(dshape)] if len(dshape) == 2
                      else ("pod", "data", "model"))
 
     cfg = reduced(args.arch) if args.reduced else get_config(args.arch)
+    cfg = cfg.with_ffn(dataclasses.replace(cfg.ffn, impl=args.impl))
     model = build_model(cfg, remat=args.remat,
                         ep_degree=mesh.shape.get("model", 1),
                         ffn=args.ffn)
@@ -125,8 +129,12 @@ def main(argv=None) -> int:
                 it.restore(extra["data"])
                 print(f"[resume] restored step {start_step}", flush=True)
 
-        step_fn = jax.jit(train_step, donate_argnums=(0,))
+        # Pinning the state's output shardings to its input shardings keeps
+        # every step on the rules' layout and on one executable.
+        step_fn = jax.jit(train_step, donate_argnums=(0,),
+                          out_shardings=(shardings, NamedSharding(mesh, P())))
         rng = jax.random.PRNGKey(args.seed + 1)
+        compiled, compile_s, history = None, 0.0, []
 
         t_start = time.time()
         try:
@@ -134,18 +142,21 @@ def main(argv=None) -> int:
                 if step == args.fail_at_step:
                     raise RuntimeError(f"injected failure at step {step}")
                 batch = {k: jnp.asarray(v) for k, v in it.next().items()}
+                if compiled is None:
+                    # jit reuses this executable on the first call
+                    t0 = time.time()
+                    compiled = step_fn.lower(state, batch, rng).compile()
+                    compile_s = time.time() - t0
+                    print(f"[compile] train step {compile_s:.1f}s", flush=True)
                 mon.start()
                 state, metrics = step_fn(state, batch, rng)
+                m = {k: float(metrics[k]) for k in ("loss", "grad_norm", "lr")}
+                history.append(dict(m, step=step))
+                dt = mon.stop(step)
                 if step % args.log_every == 0 or step == args.steps - 1:
-                    loss = float(metrics["loss"])
-                    dt = mon.stop(step)
-                    print(f"step {step:5d} loss {loss:.4f} "
-                          f"lr {float(metrics['lr']):.2e} "
-                          f"gnorm {float(metrics['grad_norm']):.3f} {dt:.3f}s",
-                          flush=True)
-                else:
-                    jax.block_until_ready(metrics["loss"])
-                    mon.stop(step)
+                    print(f"step {step:5d} loss {m['loss']:.4f} "
+                          f"lr {m['lr']:.2e} gnorm {m['grad_norm']:.3f} "
+                          f"{dt:.3f}s", flush=True)
                 if args.ckpt_every and (step + 1) % args.ckpt_every == 0:
                     mgr.save(step + 1, state, extra={"data": it.state()})
         except BaseException:
@@ -160,7 +171,7 @@ def main(argv=None) -> int:
         print(f"[done] {args.steps - start_step} steps in {total:.1f}s "
               f"({(args.steps - start_step) / max(total, 1e-9):.2f} it/s); "
               f"stragglers={len(mon.flagged)}", flush=True)
-    return 0
+    return TrainRun(history, compiled, compile_s, state)
 
 
 if __name__ == "__main__":

@@ -162,6 +162,13 @@ class LM:
                    "moe_dropped": aux["moe_dropped"], "tokens": n_tok}
         return loss, (metrics if mems is None else (metrics, new_mems))
 
+    def logits(self, params, tokens: jax.Array) -> jax.Array:
+        """(B, S, V) next-token logits of the contiguous full-sequence
+        forward (eval mode, no cache): the reference that the paged serving
+        path is checked against."""
+        h, _, _ = self.forward(params, tokens)
+        return self._unembed(params, h)
+
     # ---------------------------------------------------------------- serving
     def init_cache(self, batch: int, max_len: int) -> Dict:
         return init_stack_cache(self.cfg, batch, max_len, self.dtype)

@@ -44,15 +44,27 @@ CPU_INTERPRET = Hardware(name="cpu_interpret", peak_flops=2e11, hbm_bw=4e10,
 GPU_GENERIC = Hardware(name="gpu_generic", peak_flops=312e12, hbm_bw=2.0e12,
                        ici_bw=300e9, hbm_bytes=80e9)
 
-# jax.default_backend() name -> hardware model (kernels/autotune.py resolves
-# the backend; this module stays importable without jax).
+# jax device_kind -> model, for the TPU generations with a peak table here.
+TPU_MODELS = {"TPU v5 lite": V5E}
+
+# One model per jax backend the tuner can meet (the VMEM analyzer sweeps
+# these); a TPU's model is looked up by its device kind in TPU_MODELS.
 HARDWARE_MODELS = {"tpu": V5E, "cpu": CPU_INTERPRET, "gpu": GPU_GENERIC}
 
 
-def hardware_for(backend: str) -> Hardware:
-    """Hardware model for a jax backend name (unknown backends fall back to
-    the TPU model — conservative VMEM, TPU-shaped roofline)."""
-    return HARDWARE_MODELS.get(backend, V5E)
+def hardware_for(backend: str, device_kind: str = "") -> Hardware:
+    """Hardware model for a jax backend and device kind (kernels/autotune.py
+    reads both from ``jax.devices()[0]``; this module stays importable
+    without jax). Raises for a backend or TPU kind with no entry rather than
+    guessing another chip's peaks and VMEM."""
+    if backend == "tpu":
+        if device_kind not in TPU_MODELS:
+            raise ValueError(f"no hardware model for TPU kind {device_kind!r};"
+                             f" known: {sorted(TPU_MODELS)}")
+        return TPU_MODELS[device_kind]
+    if backend not in HARDWARE_MODELS:
+        raise ValueError(f"no hardware model for backend {backend!r}")
+    return HARDWARE_MODELS[backend]
 
 
 @dataclasses.dataclass

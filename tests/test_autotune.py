@@ -98,12 +98,22 @@ def test_heuristic_provenance_and_none(tuner):
 # ---------------------------------------------------------------------------
 
 def test_budget_from_hardware_model(tuner):
-    hw = analysis.hardware_for("tpu")
+    hw = analysis.hardware_for("tpu", "TPU v5 lite")
     assert autotune.default_vmem_budget(hw) == \
         int(hw.vmem_bytes * autotune.KERNEL_VMEM_FRACTION)
     # cvmm's module-level budget comes from the same derivation (12 MiB for
     # the 16 MiB/core models)
     assert cvmm.VMEM_BUDGET == 12 * 2**20 == autotune.default_vmem_budget()
+
+
+def test_hardware_model_from_device_kind():
+    assert analysis.hardware_for("tpu", "TPU v5 lite") is analysis.V5E
+    assert analysis.hardware_for("cpu") is analysis.CPU_INTERPRET
+    # another TPU generation or an unknown backend is an error, never v5e
+    with pytest.raises(ValueError, match="TPU kind"):
+        analysis.hardware_for("tpu", "TPU v4")
+    with pytest.raises(ValueError, match="backend"):
+        analysis.hardware_for("metal")
 
 
 def test_budget_env_override(tuner, monkeypatch):

@@ -159,6 +159,7 @@ def test_shard_map_parity_and_no_dummy_glu_weight(glu, monkeypatch):
     through shard_map (no (E,1,1) zeros placeholder, no size-1-broadcast
     einsum)."""
     from repro.core import dispatch as dispatch_mod
+    from repro.launch.mesh import make_mesh
     from repro.sharding import mesh_context
 
     cfg_e = moe_ffn(NE, G, K, dispatch="einsum", capacity_factor=8.0)
@@ -179,7 +180,7 @@ def test_shard_map_parity_and_no_dummy_glu_weight(glu, monkeypatch):
         return call
 
     monkeypatch.setattr(dispatch_mod, "_shard_map", spy)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     with mesh_context(mesh):
         ye, _ = apply_moe(p, x, cfg_e)
         ys, _ = apply_moe(p, x, cfg_s)

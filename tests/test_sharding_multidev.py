@@ -178,6 +178,7 @@ def test_compress_pod_grads_error_feedback():
 def test_sharded_train_matches_single_device():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import reduced
     from repro.configs.base import OptimizerConfig
     from repro.models import build_model
@@ -198,7 +199,7 @@ def test_sharded_train_matches_single_device():
     s1, m1 = jax.jit(step)(state1, batch, rng)
 
     # 4x2 mesh
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     with mesh_context(mesh):
         state2 = init_train_state(model, key, opt)
         state2 = jax.device_put(state2, tree_shardings(state2, mesh, TRAIN_RULES))
@@ -218,13 +219,14 @@ def test_shard_map_moe_matches_einsum():
     _run("""
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import moe_ffn
     from repro.core import apply_moe, init_moe
     from repro.sharding import mesh_context, tree_shardings, TRAIN_RULES
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     d, ne, g, k = 32, 8, 16, 2
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg_e = moe_ffn(ne, g, k, dispatch="einsum", capacity_factor=8.0)
     cfg_s = dataclasses.replace(cfg_e, dispatch="shard_map")
     p = init_moe(jax.random.PRNGKey(1), d, cfg_e, n_layers=2)
@@ -296,6 +298,7 @@ def test_shard_map_ep_matches_sort_oracle():
     _run("""
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import moe_ffn
     from repro.core import apply_moe, init_moe
     from repro.core.dispatch import ep_plan_stats
@@ -303,7 +306,7 @@ def test_shard_map_ep_matches_sort_oracle():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     d, ne, g, k, n = 32, 8, 16, 2, 64
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg_o = moe_ffn(ne, g, k, dispatch="sort", capacity_factor=8.0)
     cfg_s = dataclasses.replace(cfg_o, dispatch="shard_map")
     p = init_moe(jax.random.PRNGKey(1), d, cfg_o, n_layers=2)
@@ -408,6 +411,7 @@ def test_small_mesh_dryrun_all_modes():
     compile; roofline report extracted."""
     _run("""
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.configs import reduced, SHAPES, ShapeConfig
     from repro.configs.base import OptimizerConfig
     from repro.models import build_model
@@ -415,7 +419,7 @@ def test_small_mesh_dryrun_all_modes():
     from repro.runtime.steps import init_train_state, make_train_step
     from repro.sharding import TRAIN_RULES, SERVE_RULES, mesh_context, tree_shardings
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = reduced("granite-moe-3b-a800m")
     model = build_model(cfg, remat="full", ep_degree=2)
     shp = ShapeConfig("mini_train", 64, 8, "train")

@@ -105,10 +105,11 @@ def test_checkpoint_atomicity(tmp_path):
 def test_checkpoint_reshard_restore(tmp_path):
     """Elastic restore: save unsharded, restore with a different sharding."""
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     mgr = CheckpointManager(str(tmp_path), async_save=False)
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     mgr.save(1, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = {"w": NamedSharding(mesh, P("data", None))}
     restored, _ = mgr.restore(tree, shardings=sh)
     assert restored["w"].sharding == sh["w"]
