@@ -105,6 +105,7 @@ def _route(params: Dict, xf: jax.Array, cfg: FFNConfig, rng, train: bool,
 # Public apply: routing + the shared execution layer
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("moe")
 def apply_moe(params: Dict, x: jax.Array, cfg: FFNConfig, *,
               rng: Optional[jax.Array] = None, train: bool = False,
               collect_stats: bool = False) -> Tuple[jax.Array, Dict]:

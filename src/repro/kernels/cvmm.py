@@ -132,6 +132,14 @@ per-pass re-entry). ``python -m repro.analysis.check --all`` runs that proof
 plus the plan-invariant, VMEM-budget, and sharding-table passes; CI gates on
 it. When changing the schedule, the chunk-table layout, or a working-set
 formula, run the checker first — it fails faster than a miscompiled kernel.
+
+Names
+-----
+Every ``pallas_call`` here passes ``name=`` (``cvmm_fwd``, ``cvmm_dw``,
+``cvmm_fused_w1``, ``cvmm_gather_rows``, ``cvmm_dw_streamed``,
+``cvmm_fused_w2``). JAX opens a ``named_scope`` of that name around the
+call, so the name is the HLO instruction's name and part of its op name,
+which a device trace records (``chipbench/scopes.py`` reads it there).
 """
 from __future__ import annotations
 
@@ -256,6 +264,7 @@ def cvmm_pallas(x_pad: jax.Array, tile_expert: jax.Array, w: jax.Array,
 
     return pl.pallas_call(
         _fwd_kernel,
+        name="cvmm_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -312,6 +321,7 @@ def cvmm_dw_pallas(x_pad: jax.Array, tile_expert: jax.Array, g_pad: jax.Array,
 
     return pl.pallas_call(
         _dw_kernel,
+        name="cvmm_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -620,6 +630,7 @@ def cvmm_fused_w1_pallas(x: jax.Array, row_src: jax.Array,
 
     out = pl.pallas_call(
         kernel,
+        name="cvmm_fused_w1",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,
@@ -702,6 +713,7 @@ def cvmm_gather_rows_pallas(x: jax.Array, row_src: jax.Array,
         out_spec = pl.BlockSpec((TM, k_pad), lambda i, rs, rst, rl: (i, 0))
     return pl.pallas_call(
         functools.partial(kernel, n_buffers=n_buffers),
+        name="cvmm_gather_rows",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(m_pad // TM,),
@@ -844,6 +856,7 @@ def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
 
     return pl.pallas_call(
         functools.partial(kernel, n_buffers=n_buffers),
+        name="cvmm_dw_streamed",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,
@@ -883,6 +896,7 @@ def cvmm_fused_w2_pallas(u_pad: jax.Array, tile_expert: jax.Array,
 
     return pl.pallas_call(
         _fused_w2_kernel,
+        name="cvmm_fused_w2",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

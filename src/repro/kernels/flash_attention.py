@@ -83,6 +83,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     grid = (b * kvh * grp, sq_p // BQ)
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, causal=causal, kv_len=sk),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, BQ, d), lambda i, j: (i, j, 0)),

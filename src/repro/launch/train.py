@@ -12,6 +12,18 @@ Fault-tolerance posture (exercised by tests/test_fault_tolerance.py):
     checkpoint (restart-in-place) before re-raising persistent ones;
   * straggler monitor flags slow steps for the orchestrator.
 
+Tracing: every step runs inside ``jax.profiler.StepTraceAnnotation("train",
+step_num=step)`` with host spans ``train.batch`` (the data iterator),
+``train.step`` (the dispatch of the jitted step), ``train.readback`` (the
+metrics' device-to-host copy, where the host waits for the device) and
+``train.checkpoint`` (a save). ``--trace-dir DIR`` profiles the three steps
+after the compile step into DIR (TensorBoard's profile plugin layout,
+``DIR/plugins/profile/<run>/*.xplane.pb``), so a straggler or a device gap
+can be put down to the data iterator, the checkpoint writer or the device on
+the device trace's own clock. The train step's ops carry the program's layer
+scopes (embed, attention, moe, loss, optimizer, block) and kernel names in
+their op names.
+
 ``run(argv)`` is the same launcher as a library call: it returns the per-step
 metrics, the compiled step and the final state (chip_smoke.py checks them).
 """
@@ -60,6 +72,9 @@ def run(argv=None) -> TrainRun:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--reduced", action="store_true",
                     help="use the reduced smoke config of the arch")
+    ap.add_argument("--trace-dir", default=None,
+                    help="profile the three steps after the compile step "
+                         "into this directory")
     ap.add_argument("--fail-at-step", type=int, default=-1,
                     help="TESTING: raise at this step to exercise restart")
     args = ap.parse_args(argv)
@@ -135,36 +150,56 @@ def run(argv=None) -> TrainRun:
                           out_shardings=(shardings, NamedSharding(mesh, P())))
         rng = jax.random.PRNGKey(args.seed + 1)
         compiled, compile_s, history = None, 0.0, []
+        span = jax.profiler.TraceAnnotation
+        tracing = False
 
         t_start = time.time()
         try:
             for step in range(start_step, args.steps):
                 if step == args.fail_at_step:
                     raise RuntimeError(f"injected failure at step {step}")
-                batch = {k: jnp.asarray(v) for k, v in it.next().items()}
-                if compiled is None:
-                    # jit reuses this executable on the first call
-                    t0 = time.time()
-                    compiled = step_fn.lower(state, batch, rng).compile()
-                    compile_s = time.time() - t0
-                    print(f"[compile] train step {compile_s:.1f}s", flush=True)
-                mon.start()
-                state, metrics = step_fn(state, batch, rng)
-                m = {k: float(metrics[k]) for k in ("loss", "grad_norm", "lr")}
-                history.append(dict(m, step=step))
-                dt = mon.stop(step)
-                if step % args.log_every == 0 or step == args.steps - 1:
-                    print(f"step {step:5d} loss {m['loss']:.4f} "
-                          f"lr {m['lr']:.2e} gnorm {m['grad_norm']:.3f} "
-                          f"{dt:.3f}s", flush=True)
-                if args.ckpt_every and (step + 1) % args.ckpt_every == 0:
-                    mgr.save(step + 1, state, extra={"data": it.state()})
+                if args.trace_dir and step == start_step + 1:
+                    jax.profiler.start_trace(args.trace_dir)
+                    tracing = True
+                with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                    with span("train.batch"):
+                        batch = {k: jnp.asarray(v)
+                                 for k, v in it.next().items()}
+                    if compiled is None:
+                        # jit reuses this executable on the first call
+                        t0 = time.time()
+                        compiled = step_fn.lower(state, batch, rng).compile()
+                        compile_s = time.time() - t0
+                        print(f"[compile] train step {compile_s:.1f}s",
+                              flush=True)
+                    mon.start()
+                    with span("train.step"):
+                        state, metrics = step_fn(state, batch, rng)
+                    with span("train.readback"):
+                        m = {k: float(metrics[k])
+                             for k in ("loss", "grad_norm", "lr")}
+                    history.append(dict(m, step=step))
+                    dt = mon.stop(step)
+                    if step % args.log_every == 0 or step == args.steps - 1:
+                        print(f"step {step:5d} loss {m['loss']:.4f} "
+                              f"lr {m['lr']:.2e} gnorm {m['grad_norm']:.3f} "
+                              f"{dt:.3f}s", flush=True)
+                    if args.ckpt_every and (step + 1) % args.ckpt_every == 0:
+                        with span("train.checkpoint"):
+                            mgr.save(step + 1, state,
+                                     extra={"data": it.state()})
+                if tracing and step == start_step + 3:
+                    jax.profiler.stop_trace()
+                    tracing = False
         except BaseException:
             # Preemption/crash path: an async save started before the failure
             # must still commit, or "loses at most ckpt_every steps" is a lie —
             # the daemon writer thread dies with the process mid-write.
             mgr.wait()
             raise
+        finally:
+            if tracing:
+                jax.profiler.stop_trace()
         mgr.save(args.steps, state, extra={"data": it.state()}, blocking=True)
         mgr.wait()
         total = time.time() - t_start

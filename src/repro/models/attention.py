@@ -227,6 +227,7 @@ def paged_attend(q: jax.Array, k: jax.Array, v: jax.Array, cache: Dict,
 # Transformer-XL relative-position attention (paper's baseline architecture)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("rel_shift")
 def _rel_shift(x: jax.Array) -> jax.Array:
     """(B,H,Sq,Sk) BD-term shift (Dai et al. 2019)."""
     b, h, sq, sk = x.shape
@@ -259,6 +260,7 @@ def xl_attention(params: Dict, q: jax.Array, k: jax.Array, v: jax.Array,
 # Full block-level apply
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def apply_attention(params: Dict, x: jax.Array, cfg: ModelConfig, *,
                     kind: str = "", positions: Optional[jax.Array] = None,
                     cache: Optional[Dict] = None,

@@ -80,6 +80,7 @@ class LM:
             pos_encoding="learned")
 
     # -------------------------------------------------------------- embedding
+    @jax.named_scope("embed")
     def _embed(self, params, tokens, *, prefix_embeds=None, pos_offset=0):
         cfg = self.cfg
         x = params["emb"].astype(self.dtype)[tokens]
@@ -150,13 +151,14 @@ class LM:
             params, tokens, prefix_embeds=prefix, frames=batch.get("frames"),
             rng=rng, train=train, mems=mems)
         n_prefix = prefix.shape[1] if prefix is not None else 0
-        h_text = h[:, n_prefix:, :]
-        w = (params["emb"].T if cfg.tie_embeddings else params["unembed"])
-        ce, n_tok = chunked_cross_entropy(
-            h_text[:, :-1], w.astype(h_text.dtype), tokens[:, 1:],
-            chunks=self.ce_chunks, softcap=cfg.logit_softcap,
-            n_valid_vocab=(cfg.vocab_size
-                           if self.vocab_padded != cfg.vocab_size else 0))
+        with jax.named_scope("loss"):
+            h_text = h[:, n_prefix:, :]
+            w = (params["emb"].T if cfg.tie_embeddings else params["unembed"])
+            ce, n_tok = chunked_cross_entropy(
+                h_text[:, :-1], w.astype(h_text.dtype), tokens[:, 1:],
+                chunks=self.ce_chunks, softcap=cfg.logit_softcap,
+                n_valid_vocab=(cfg.vocab_size
+                               if self.vocab_padded != cfg.vocab_size else 0))
         loss = ce + aux["moe_reg"]
         metrics = {"ce": ce, "moe_reg": aux["moe_reg"],
                    "moe_dropped": aux["moe_dropped"], "tokens": n_tok}

@@ -108,7 +108,15 @@ def apply_block(params: Dict, shared: Optional[Dict], x: jax.Array,
     if rng is not None:
         r1, r2, r3 = jax.random.split(rng, 3)
 
-    def constrain(h):
+    # Norms and residual adds run under a "block" scope of their own, beside
+    # the sublayers' "attention" and "moe" scopes (profiler op names).
+    @jax.named_scope("block")
+    def norm(p, h):
+        return apply_norm(p, h, cfg)
+
+    @jax.named_scope("block")
+    def residual(h, r, y):
+        h = h + dropout(r, y, cfg.dropout, train)
         return (with_logical_constraint(h, ("batch", "seq", None), SP_RULES)
                 if sp else h)
 
@@ -119,10 +127,11 @@ def apply_block(params: Dict, shared: Optional[Dict], x: jax.Array,
         mixer = "attn"
 
     if mixer == "attn":
-        h = apply_norm(mixer_params["norm1"], x, cfg)
+        h = norm(mixer_params["norm1"], x)
         if cfg.pos_encoding == "xl_rel" and memory is not None:
-            new_memory = jax.lax.stop_gradient(
-                jnp.concatenate([memory.astype(x.dtype), h], axis=1)[:, -memory.shape[1]:])
+            with jax.named_scope("block"):
+                new_memory = jax.lax.stop_gradient(jnp.concatenate(
+                    [memory.astype(x.dtype), h], axis=1)[:, -memory.shape[1]:])
         y, c = apply_attention(mixer_params["attn"], h, cfg,
                                kind=entry.attn_kind, positions=positions,
                                cache=cache.get("self") if cache else None,
@@ -130,17 +139,17 @@ def apply_block(params: Dict, shared: Optional[Dict], x: jax.Array,
                                block_table=block_table, seq_lens=seq_lens)
         if c is not None:
             new_cache["self"] = c
-        x = constrain(x + dropout(r1, y, cfg.dropout, train))
+        x = residual(x, r1, y)
     elif mixer == "ssm":
-        h = apply_norm(params["norm1"], x, cfg)
+        h = norm(params["norm1"], x)
         y, c = apply_ssm(params["ssm"], h, cfg,
                          cache=cache.get("ssm") if cache else None)
         if c is not None:
             new_cache["ssm"] = c
-        x = constrain(x + dropout(r1, y, cfg.dropout, train))
+        x = residual(x, r1, y)
 
     if "cross" in params and (enc_out is not None or cross_cache is not None):
-        h = apply_norm(params["norm_x"], x, cfg)
+        h = norm(params["norm_x"], x)
         if cross_cache is not None:
             kv = (cross_cache["k"].astype(h.dtype), cross_cache["v"].astype(h.dtype))
             y, _ = apply_attention(params["cross"], h, cfg, positions=positions,
@@ -148,16 +157,16 @@ def apply_block(params: Dict, shared: Optional[Dict], x: jax.Array,
             new_cache["cross"] = cross_cache      # static after prefill; pass through
         else:
             y, _ = _cross_attend(params["cross"], h, enc_out, cfg, positions)
-        x = constrain(x + dropout(r3, y, cfg.dropout, train))
+        x = residual(x, r3, y)
 
     ffn_kind = entry.ffn
     if ffn_kind != "none":
         fp = shared["ffn"] if ffn_kind == "shared_ffn" else params["ffn"]
         fn = shared["norm2"] if ffn_kind == "shared_ffn" else params["norm2"]
-        h = apply_norm(fn, x, cfg)
+        h = norm(fn, x)
         y, faux = apply_ffn(fp, h, cfg.ffn, rng=r2, train=train)
         aux = {k: aux[k] + faux.get(k, 0.0) for k in aux}
-        x = constrain(x + dropout(r2, y, cfg.dropout, train))
+        x = residual(x, r2, y)
     return x, aux, (new_cache or None), new_memory
 
 

@@ -156,18 +156,19 @@ def make_train_step(model: LM, opt_cfg: OptimizerConfig,
             grads, new_err = compress_pod_grads(grads_pp, state["err"],
                                                 opt_cfg.grad_compression)
             new_state["err"] = new_err
-            grads, gnorm = clip_by_global_norm(grads, opt_cfg.grad_clip)
             new_mems = None
         else:
             loss, metrics, new_mems, grads = compute_grads(params, batch, rng,
                                                            mems)
+        with jax.named_scope("optimizer"):
             grads, gnorm = clip_by_global_norm(grads, opt_cfg.grad_clip)
-            if "err" in state:
+            if "err" in state and not pod_tier:
                 grads, new_err = compress_grads(grads, state["err"],
                                                 opt_cfg.grad_compression)
                 new_state["err"] = new_err
-        lr = sched(state["opt"].step)
-        new_params, new_opt = adamw_update(grads, state["opt"], params, opt_cfg, lr)
+            lr = sched(state["opt"].step)
+            new_params, new_opt = adamw_update(grads, state["opt"], params,
+                                               opt_cfg, lr)
         new_state["params"] = new_params
         new_state["opt"] = new_opt
         if new_mems is not None:

@@ -5,8 +5,10 @@ chip's compiler refuses (a block or DMA slice that is not aligned to the
 (8, 128) tiling, a scratch that overflows VMEM). These tests compile the real
 kernels with ``interpret=False`` for one chip of a described ``v5e:2x2``
 topology at the published widths of the models the repo runs, and check that
-each compiled program holds the Mosaic kernel (``tpu_custom_call``). Nothing
-runs: the TPU compiler is installed with jaxlib and needs no chip.
+each compiled program holds the Mosaic kernel (``tpu_custom_call``). The
+last tests compile a cut-down train step and check the layer scopes and
+kernel names in its op names. Nothing runs: the TPU compiler is installed
+with jaxlib and needs no chip.
 
 The topology is described inside a module fixture (never at import), so
 every pytest-xdist worker collects the same tests and only the worker that
@@ -14,7 +16,9 @@ runs this file loads the TPU compiler.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -136,3 +140,110 @@ def test_moe_mlp_decode_compiles(one_chip):
                     _spec(one_chip, (e, d, g), dtype))
     # row gather + w1 + w1g + w2
     assert text.count("tpu_custom_call") >= 4
+
+
+# ---------------------------------------------------------------------------
+# The train step names its layers and its kernels in the program.
+#
+# Each layer boundary of the step opens a ``jax.named_scope`` (``embed``,
+# ``attention``, ``moe``, ``loss``, ``optimizer``, and ``block`` for norms and
+# residual adds) and every Pallas call carries a kernel name; both reach the
+# op-name metadata of the compiled step, which a device trace records as each
+# op's ``tf_op`` and the benchmark sums device time by (chipbench/scopes.py).
+# A refactor that drops one would turn a per-layer metric null. The step is
+# wt103-262m-moe cut to 2 layers at its published widths (XL memory 128,
+# batch 4 x 128), with the sort dispatch on the fused kernels and full remat.
+# ---------------------------------------------------------------------------
+
+LAYERS = ("embed", "attention", "moe", "loss", "block")
+SORT_KERNELS = {"cvmm_fused_w1", "cvmm_fused_w2", "cvmm_dw_streamed",
+                "cvmm_fwd"}
+KERNELS = SORT_KERNELS | {"cvmm_dw", "cvmm_gather_rows", "flash_attention"}
+_WRAPPED = re.compile(r"^(?:(?:jvp|transpose)\()+(.*?)\)*$")
+
+
+def _scopes(op_name: str):
+    """The components of an op name, transform wrappers taken off."""
+    parts = op_name.split("/")
+    return [m.group(1) if m else p
+            for p, m in zip(parts, map(_WRAPPED.match, parts))]
+
+
+@pytest.fixture(scope="module")
+def compiled_step(one_chip):
+    """op names of the compiled train step (see the section header)."""
+    from repro.configs import OptimizerConfig, get_config
+    from repro.models import build_model
+    from repro.models.stack import init_mems
+    from repro.optim import adamw_init
+    from repro.runtime.steps import make_train_step
+
+    cfg = get_config("wt103-262m-moe").override(n_layers=2, xl_memory=128,
+                                                dropout=0.0)
+    cfg = cfg.with_ffn(dataclasses.replace(cfg.ffn, impl="pallas_fused",
+                                           expert_dropout=0.0))
+    model = build_model(cfg, remat="full")
+    step = make_train_step(model, OptimizerConfig())
+
+    def spec(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    state = jax.eval_shape(lambda k: (lambda p: {
+        "params": p, "opt": adamw_init(p),
+        "mems": init_mems(cfg, 4, model.dtype)})(model.init(k)),
+        jax.random.PRNGKey(0))
+    args = jax.tree_util.tree_map(spec, (
+        state, {"tokens": jax.ShapeDtypeStruct((4, 129), jnp.int32)},
+        jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    # The kernels lower for the chip only where the repo sees a TPU backend.
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(step).lower(*args).compile().as_text()
+    ops = re.findall(r'^\s*(?:ROOT )?%(\S+) = (.*?)metadata=\{op_name="([^"]*)"',
+                     text, re.M)
+    # every Mosaic call has op-name metadata, so none is missed below
+    assert sum('"tpu_custom_call"' in rhs for _, rhs, _ in ops) \
+        == text.count('custom_call_target="tpu_custom_call"') > 0
+    return ops
+
+
+def _names(ops, backward: bool, remat: bool = False):
+    return [_scopes(name) for _, _, name in ops
+            if ("transpose(" in name) == backward
+            and ("rematted_computation" in name) == remat]
+
+
+@pytest.mark.parametrize("layer", LAYERS)
+def test_layer_scope_in_forward_and_backward(compiled_step, layer):
+    assert any(layer in s for s in _names(compiled_step, backward=False))
+    assert any(layer in s for s in _names(compiled_step, backward=True))
+
+
+@pytest.mark.parametrize("layer", ["attention", "moe", "block"])
+def test_layer_scope_in_recomputed_forward(compiled_step, layer):
+    # full remat: the backward scan recomputes each block's forward
+    assert any(layer in s for s in _names(compiled_step, backward=True,
+                                          remat=True))
+
+
+def test_optimizer_scope(compiled_step):
+    names = _names(compiled_step, backward=False)
+    opt = [s for s in names if "optimizer" in s]
+    assert opt
+    # the update runs once per step, outside every layer of the model
+    assert not any(set(LAYERS) & set(s) for s in opt)
+
+
+def test_every_pallas_call_carries_its_kernel_name(compiled_step):
+    found = set()
+    for inst, rhs, name in compiled_step:
+        if 'custom_call_target="tpu_custom_call"' not in rhs:
+            continue
+        kernel = [s for s in _scopes(name) if s in KERNELS]
+        assert kernel, name
+        # the kernel's name is also the HLO instruction's name, and the
+        # kernel runs under the moe scope, forward and backward
+        assert inst.split(".")[0] == kernel[0]
+        assert "moe" in _scopes(name)
+        found.add(kernel[0])
+    assert found == SORT_KERNELS
