@@ -1,9 +1,12 @@
 #!/usr/bin/env python3
 """Bring the main paths up on a TPU and check what they compute.
 
-    python3 chip_smoke.py              # train + serve phases on one chip
+    python3 chip_smoke.py              # xl_rel, train, serve on one chip
     python3 chip_smoke.py --chips 4    # mesh training on four chips only
 
+xl_rel the shifted BD kernel of xl_rel attention against the XLA path it
+       replaces, forward and VJP, at the training shapes and at one query,
+       no memory, and every residue of the query count mod 8.
 train  wt103-262m-moe as registered (paper Tab. 8/9: 18 layers, d_model 1024,
        32 experts of 128, k=4, xl_rel attention with 512 memory), batch 8 x
        seq 512, 6 steps through ``repro.launch.train`` on a 1x1 mesh, with
@@ -18,7 +21,8 @@ serve  granite-moe-3b-a800m at its published widths with bf16 parameters and
        contiguous forward with ``impl="ragged"``.
 --chips 4  the train phase on ``--mesh 4x1`` and ``--mesh 2x2`` against the
        same job on one chip: losses agree, and every parameter sits on four
-       devices with the sharding the rules give it.
+       devices with the sharding the rules give it. The Mosaic kernels run
+       per device inside ``shard_map``s (the sort dispatch, xl_rel's BD term).
 
 Weights are random, made from ``--seed``. Everything runs in this one process.
 A failed check raises, so the script exits non-zero; where JAX finds no TPU it
@@ -29,6 +33,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 import shutil
@@ -79,6 +84,73 @@ def sort_rung(cfg, dtype) -> str:
     f = cfg.ffn
     return ops.plan_sort_kernels(resolve_impl(f), cfg.d_model, f.expert_size,
                                  f.activation, dtype, glu=f.glu_experts).rung
+
+
+# ---------------------------------------------------------------------------
+# xl_rel
+# ---------------------------------------------------------------------------
+
+# (batch, heads, head_dim, queries, keys): the training shapes of wt103-262m
+# and enwik8-41m (rows of 512 + 1 over 512 of memory) and of wt103-47m
+# (256 + 1 over 256), then one query row, no memory, and eight queries
+# 300-307, one of each residue mod 8. Mosaic's strided roll went wrong at
+# some residues and rotations that interpret mode and the training shapes
+# never showed (kernels/xl_rel.py, SUBLANE).
+XL_REL_SHAPES = ((16, 16, 64, 513, 1025), (16, 10, 41, 257, 513),
+                 (2, 3, 41, 37, 301), (2, 2, 41, 300, 300),
+                 (2, 2, 64, 1, 513)) + tuple(
+                     (1, 2, 64, 300 + k, 511 + k) for k in range(8))
+# bfloat16 inputs, float32 accumulation on both paths. A forward value may
+# differ by one bfloat16 rounding of the tensor's largest; the VJP's two
+# outputs by 2**-8 in norm. A shifted row is off by the whole value and a
+# wrongly rolled cotangent by percents (the faults seen: 3% to 16x).
+XL_REL_FWD_RTOL = 2.0 ** -8
+XL_REL_VJP_RTOL = 2.0 ** -8
+
+
+def xl_rel_phase(shapes=XL_REL_SHAPES, *, seed: int = 0,
+                 interpret: bool = False):
+    """The shifted BD kernel (``kernels/xl_rel.py``) against the XLA path it
+    replaces on TPU, einsum + ``_rel_shift``: the forward on the causal-valid
+    region, and the VJP of a cotangent on that region (d(q + v_bias), dr).
+    Returns the largest (forward, VJP) relative errors."""
+    from repro.kernels.xl_rel import xl_rel_bd
+    from repro.models.attention import _rel_shift
+
+    def xla(qv, r):
+        return _rel_shift(jnp.einsum("bqhd,khd->bhqk", qv, r))
+
+    def both(f, qv, r, g):
+        out, vjp = jax.vjp(f, qv, r)
+        return out, vjp(g)
+
+    kernel = jax.jit(functools.partial(
+        both, functools.partial(xl_rel_bd, interpret=interpret)))
+    reference = jax.jit(functools.partial(both, xla))
+    worst = [0.0, 0.0]
+    for b, h, d, sq, sk in shapes:
+        keys = jax.random.split(jax.random.fold_in(
+            jax.random.PRNGKey(seed), sq * 4096 + sk), 3)
+        qv = jax.random.normal(keys[0], (b, sq, h, d)).astype(jnp.bfloat16)
+        r = jax.random.normal(keys[1], (sk, h, d)).astype(jnp.bfloat16)
+        valid = jnp.arange(sk)[None, :] <= (sk - sq) + jnp.arange(sq)[:, None]
+        g = (jax.random.normal(keys[2], (b, h, sq, sk))
+             * valid).astype(jnp.bfloat16)
+        (got, got_ct), (want, want_ct) = kernel(qv, r, g), reference(qv, r, g)
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        fwd = float(jnp.max(jnp.where(valid, jnp.abs(got - want), 0))
+                    / jnp.max(jnp.abs(want)))
+        vjp = max(float(jnp.linalg.norm((a.astype(jnp.float32)
+                                         - e.astype(jnp.float32)).ravel())
+                        / jnp.linalg.norm(e.astype(jnp.float32).ravel()))
+                  for a, e in zip(got_ct, want_ct))
+        check(bool(jnp.all(jnp.isfinite(got))) and fwd <= XL_REL_FWD_RTOL
+              and vjp <= XL_REL_VJP_RTOL,
+              f"xl_rel: {(b, h, d, sq, sk)} finite, forward {fwd:.2e} <= "
+              f"{XL_REL_FWD_RTOL:.2e}, VJP {vjp:.2e} <= {XL_REL_VJP_RTOL:.2e}"
+              f" of the XLA path")
+        worst = [max(worst[0], fwd), max(worst[1], vjp)]
+    return tuple(worst)
 
 
 # ---------------------------------------------------------------------------
@@ -317,6 +389,7 @@ def main(argv=None) -> int:
         four_chip_phase(seed=args.seed)
         print(f"[four-chip] {_memory_line(dev)}", flush=True)
     else:
+        xl_rel_phase(seed=args.seed)
         from repro.configs import get_config
         rung = sort_rung(get_config(TRAIN_ARCH), jnp.bfloat16)
         print(f"[train] {TRAIN_ARCH} sort rung {rung}", flush=True)

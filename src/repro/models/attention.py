@@ -9,12 +9,17 @@ XLA also compiles well (it is the same loop structure the kernel uses).
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..configs.base import AttentionConfig, ModelConfig
+from ..kernels.xl_rel import xl_rel_bd
+from ..sharding.context import current_mesh
+from ..sharding.logical import TRAIN_RULES
 from .layers import apply_rope, rms_norm_simple, sinusoid_positions
 
 
@@ -227,13 +232,42 @@ def paged_attend(q: jax.Array, k: jax.Array, v: jax.Array, cache: Dict,
 # Transformer-XL relative-position attention (paper's baseline architecture)
 # ---------------------------------------------------------------------------
 
-@jax.named_scope("rel_shift")
 def _rel_shift(x: jax.Array) -> jax.Array:
     """(B,H,Sq,Sk) BD-term shift (Dai et al. 2019)."""
     b, h, sq, sk = x.shape
     x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (1, 0)))
     x = x.reshape(b, h, sk + 1, sq)[:, :, 1:, :]
     return x.reshape(b, h, sq, sk)
+
+
+@jax.named_scope("rel_shift")
+def _rel_bd(qv: jax.Array, r: jax.Array) -> jax.Array:
+    """The shifted BD term (B,H,Sq,Sk): on TPU one Pallas kernel that shifts
+    in VMEM (kernels/xl_rel.py), per device under a mesh; elsewhere the
+    product and ``_rel_shift``."""
+    if jax.default_backend() != "tpu":
+        return _rel_shift(jnp.einsum("bqhd,khd->bhqk", qv, r))
+    mesh = current_mesh()
+    if mesh is None:
+        return xl_rel_bd(qv, r)
+    # GSPMD cannot partition a Mosaic call, so under a mesh every device runs
+    # the kernel on its own block. (batch, head) blocks are independent: the
+    # batch splits as the training rules split it, the heads likewise, each
+    # only where its mesh axes divide it (else that dimension stays whole).
+    # check_vma=False as in core/dispatch: the transpose then psums r's
+    # cotangent, the per-device partial dR, over the axes r is replicated on.
+    def axes(rule, n):
+        names = tuple(a for a in ((rule,) if isinstance(rule, str) else rule)
+                      if a in mesh.axis_names)
+        size = math.prod(mesh.shape[a] for a in names)
+        return names if names and n % size == 0 else None
+
+    b, _, h, _ = qv.shape
+    batch, heads = axes(TRAIN_RULES["batch"], b), axes(TRAIN_RULES["heads"], h)
+    return jax.shard_map(
+        xl_rel_bd, mesh=mesh,
+        in_specs=(P(batch, None, heads, None), P(None, heads, None)),
+        out_specs=P(batch, heads, None, None), check_vma=False)(qv, r)
 
 
 def xl_attention(params: Dict, q: jax.Array, k: jax.Array, v: jax.Array,
@@ -245,8 +279,7 @@ def xl_attention(params: Dict, q: jax.Array, k: jax.Array, v: jax.Array,
     r = sinusoid_positions(sk, d_model, q.dtype)[::-1]        # distances sk-1..0
     r = (r @ params["w_r"].astype(q.dtype)).reshape(sk, h, dh)
     ac = jnp.einsum("bqhd,bkhd->bhqk", q + params["u_bias"].astype(q.dtype), k)
-    bd = jnp.einsum("bqhd,khd->bhqk", q + params["v_bias"].astype(q.dtype), r)
-    bd = _rel_shift(bd)
+    bd = _rel_bd(q + params["v_bias"].astype(q.dtype), r)
     s = (ac + bd).astype(jnp.float32) * scale
     q_pos = (sk - sq) + jnp.arange(sq)
     mask = q_pos[:, None] >= jnp.arange(sk)[None, :]

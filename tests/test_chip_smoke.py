@@ -10,8 +10,13 @@ import os
 import subprocess
 import sys
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from repro.kernels import xl_rel
+from repro.models import attention
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -46,6 +51,40 @@ def test_serve_phase_reduced(smoke):
     assert np.all(np.isfinite(out["err"])) and out["err"].max() > 0
     assert out["counters"]["rebuilds"] > 0
     assert 0.0 <= out["agree"] <= 1.0
+
+
+XL_REL_SMALL = ((2, 3, 41, 13, 29), (1, 2, 16, 8, 8), (1, 2, 16, 1, 9))
+
+
+def test_xl_rel_phase_reduced(smoke):
+    fwd, vjp = smoke.xl_rel_phase(XL_REL_SMALL, interpret=True)
+    assert 0 <= fwd <= smoke.XL_REL_FWD_RTOL
+    assert 0 <= vjp <= smoke.XL_REL_VJP_RTOL
+
+
+def _unshifted(qv, r, interpret=False):
+    return jnp.einsum("bqhd,khd->bhqk", qv, r)
+
+
+@jax.custom_vjp
+def _unrolled_backward(qv, r):
+    return attention._rel_shift(_unshifted(qv, r))
+
+
+_unrolled_backward.defvjp(
+    lambda qv, r: (_unrolled_backward(qv, r), (qv, r)),
+    # the cotangent taken as the unshifted product's: never rolled back
+    lambda res, g: jax.vjp(_unshifted, *res)[1](g))
+
+
+@pytest.mark.parametrize("fault", [
+    _unshifted,
+    lambda qv, r, interpret=False: _unrolled_backward(qv, r),
+], ids=["forward_not_shifted", "backward_not_rolled"])
+def test_xl_rel_phase_catches_a_planted_fault(smoke, monkeypatch, fault):
+    monkeypatch.setattr(xl_rel, "xl_rel_bd", fault)
+    with pytest.raises(smoke.SmokeError):
+        smoke.xl_rel_phase(XL_REL_SMALL[:1], interpret=True)
 
 
 def test_check_raises_on_failure(smoke):

@@ -37,7 +37,8 @@ WIDTHS = {
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The devices of a described v5e:2x2 (nothing runs on them)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -51,8 +52,13 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _spec(sharding, shape, dtype):
@@ -122,6 +128,31 @@ def test_dedup_weighted_gather_compiles(one_chip, dtype):
     assert "tpu_custom_call" in text
 
 
+# (batch, heads, head_dim, queries, keys) of xl_rel attention in training:
+# rows of 512 + 1 tokens over 512 of memory, and 256 + 1 over 256.
+XL_REL_WIDTHS = {
+    "wt103-262m": (16, 16, 64, 513, 1025),
+    "wt103-47m": (16, 10, 41, 257, 513),
+}
+
+
+@pytest.mark.parametrize("train", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("name", sorted(XL_REL_WIDTHS))
+def test_xl_rel_bd_compiles(one_chip, name, train):
+    from repro.kernels.xl_rel import xl_rel_bd
+    b, h, d, sq, sk = XL_REL_WIDTHS[name]
+
+    def loss(qv, r):
+        return jnp.sum(xl_rel_bd(qv, r).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1)) if train else xl_rel_bd
+    text = _compile(fn, _spec(one_chip, (b, sq, h, d), jnp.bfloat16),
+                    _spec(one_chip, (sk, h, d), jnp.bfloat16))
+    # under grad the forward kernel is dead (the loss is linear in it)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert ("xl_rel_bd_bwd" in text) == train
+
+
 def test_moe_mlp_decode_compiles(one_chip):
     d, g, e, k, glu, act, _ = WIDTHS["granite-moe-3b-a800m"]
     lanes, dtype = 8, jnp.bfloat16
@@ -158,7 +189,9 @@ def test_moe_mlp_decode_compiles(one_chip):
 LAYERS = ("embed", "attention", "moe", "loss", "block")
 SORT_KERNELS = {"cvmm_fused_w1", "cvmm_fused_w2", "cvmm_dw_streamed",
                 "cvmm_fwd"}
-KERNELS = SORT_KERNELS | {"cvmm_dw", "cvmm_gather_rows", "flash_attention"}
+XL_REL_KERNELS = {"xl_rel_bd", "xl_rel_bd_bwd"}
+KERNELS = SORT_KERNELS | XL_REL_KERNELS | {"cvmm_dw", "cvmm_gather_rows",
+                                          "flash_attention"}
 _WRAPPED = re.compile(r"^(?:(?:jvp|transpose)\()+(.*?)\)*$")
 
 
@@ -169,36 +202,52 @@ def _scopes(op_name: str):
             for p, m in zip(parts, map(_WRAPPED.match, parts))]
 
 
-@pytest.fixture(scope="module")
-def compiled_step(one_chip):
-    """op names of the compiled train step (see the section header)."""
+def _compile_step(arch: str, mesh=None, one_chip=None) -> str:
+    """The compiled text of ``arch``'s train step cut to 2 layers (see the
+    section header), on ``mesh`` with the training rules' shardings, as the
+    launcher jits it, or on ``one_chip``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     from repro.configs import OptimizerConfig, get_config
     from repro.models import build_model
     from repro.models.stack import init_mems
     from repro.optim import adamw_init
     from repro.runtime.steps import make_train_step
+    from repro.sharding import TRAIN_RULES, mesh_context, tree_shardings
 
-    cfg = get_config("wt103-262m-moe").override(n_layers=2, xl_memory=128,
-                                                dropout=0.0)
+    cfg = get_config(arch).override(n_layers=2, xl_memory=128, dropout=0.0)
     cfg = cfg.with_ffn(dataclasses.replace(cfg.ffn, impl="pallas_fused",
                                            expert_dropout=0.0))
     model = build_model(cfg, remat="full")
-    step = make_train_step(model, OptimizerConfig())
-
-    def spec(s):
-        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
-
+    step = make_train_step(model, OptimizerConfig(), mesh=mesh)
     state = jax.eval_shape(lambda k: (lambda p: {
         "params": p, "opt": adamw_init(p),
         "mems": init_mems(cfg, 4, model.dtype)})(model.init(k)),
         jax.random.PRNGKey(0))
-    args = jax.tree_util.tree_map(spec, (
-        state, {"tokens": jax.ShapeDtypeStruct((4, 129), jnp.int32)},
-        jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    args = (state, {"tokens": jax.ShapeDtypeStruct((4, 129), jnp.int32)},
+            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    if mesh is None:
+        shardings = jax.tree_util.tree_map(lambda _: one_chip, args)
+        out = None
+    else:
+        state_sh = tree_shardings(state, mesh, TRAIN_RULES)
+        shardings = (state_sh, {"tokens": NamedSharding(mesh, P("data"))},
+                     NamedSharding(mesh, P()))
+        out = (state_sh, NamedSharding(mesh, P()))
+    args = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        args, shardings)
     # The kernels lower for the chip only where the repo sees a TPU backend.
-    with pytest.MonkeyPatch.context() as mp:
+    with pytest.MonkeyPatch.context() as mp, mesh_context(mesh):
         mp.setattr(jax, "default_backend", lambda: "tpu")
-        text = jax.jit(step).lower(*args).compile().as_text()
+        fn = jax.jit(step) if out is None else jax.jit(step, out_shardings=out)
+        return fn.lower(*args).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def compiled_step(one_chip):
+    """op names of the compiled train step (see the section header)."""
+    text = _compile_step("wt103-262m-moe", one_chip=one_chip)
     ops = re.findall(r'^\s*(?:ROOT )?%(\S+) = (.*?)metadata=\{op_name="([^"]*)"',
                      text, re.M)
     # every Mosaic call has op-name metadata, so none is missed below
@@ -239,11 +288,66 @@ def test_every_pallas_call_carries_its_kernel_name(compiled_step):
     for inst, rhs, name in compiled_step:
         if 'custom_call_target="tpu_custom_call"' not in rhs:
             continue
-        kernel = [s for s in _scopes(name) if s in KERNELS]
+        scopes = _scopes(name)
+        kernel = [s for s in scopes if s in KERNELS]
         assert kernel, name
-        # the kernel's name is also the HLO instruction's name, and the
-        # kernel runs under the moe scope, forward and backward
+        # the kernel's name is also the HLO instruction's name; the sort
+        # kernels run under moe, the shifted BD term under attention's
+        # rel_shift
         assert inst.split(".")[0] == kernel[0]
-        assert "moe" in _scopes(name)
+        if kernel[0] in XL_REL_KERNELS:
+            assert {"attention", "rel_shift"} <= set(scopes), name
+        else:
+            assert "moe" in scopes, name
         found.add(kernel[0])
-    assert found == SORT_KERNELS
+    assert found == SORT_KERNELS | XL_REL_KERNELS
+
+
+@pytest.mark.parametrize("backward,remat,kernel", [
+    (False, False, "xl_rel_bd"),
+    (True, True, "xl_rel_bd"),
+    (True, False, "xl_rel_bd_bwd"),
+], ids=["forward", "recomputed_forward", "backward"])
+def test_xl_rel_bd_runs_in_every_pass(compiled_step, backward, remat, kernel):
+    assert any(kernel in s and "rel_shift" in s
+               for s in _names(compiled_step, backward=backward, remat=remat))
+
+
+def test_rel_shift_moves_no_scores(compiled_step):
+    # The scores of the cut-down step are (4, 16, 129, 257). The relative
+    # shift of the XLA path pads, reshapes and slices tensors of that size
+    # under rel_shift; with the kernel only (batch, queries, heads, dim) and
+    # (keys, heads, dim) layouts of its operands remain there.
+    scores = 4 * 16 * 129 * 257
+    in_scope = 0
+    for _, rhs, name in compiled_step:
+        if ("rel_shift" not in _scopes(name)
+                or 'custom_call_target="tpu_custom_call"' in rhs):
+            continue
+        in_scope += 1
+        shape = re.match(r"\(?\w+\[([\d,]*)\]", rhs)
+        size = 1
+        for n in filter(None, shape.group(1).split(",")):
+            size *= int(n)
+        assert size < scores // 4, (rhs[:80], name)
+    assert in_scope
+
+
+@pytest.mark.parametrize("arch,shape", [
+    ("wt103-262m-moe", (2, 2)),
+    ("wt103-47m-moe", (1, 4)),
+], ids=["wt103-262m-2x2", "wt103-47m-1x4"])
+def test_train_step_compiles_on_a_mesh(v5e_2x2, arch, shape):
+    # GSPMD cannot partition a Mosaic call: on a mesh of four chips the
+    # step lowers only if every kernel runs per device inside a shard_map.
+    # 2x2 splits batch and heads; wt103-47m's 10 heads do not split 4 ways.
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    mesh = Mesh(np.asarray(v5e_2x2).reshape(shape), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    text = _compile_step(arch, mesh=mesh)
+    found = {m.split(".")[0] for m in re.findall(
+        r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target="tpu_custom_call"',
+        text, re.M)}
+    assert found == SORT_KERNELS | XL_REL_KERNELS
