@@ -177,6 +177,31 @@ def whisper_tiny() -> ModelConfig:
     )
 
 
+@register("moonlight-16b-a3b")
+def moonlight_16b_a3b() -> ModelConfig:
+    """[moe] DeepSeek-V3 architecture at 16B (3B active): MLA, a dense first
+    layer, 64 routed SiLU-GLU experts of 1408 (top-6 by sigmoid score plus a
+    selection-only bias, renormalized and scaled by 2.446) and 2 shared.
+    hf:moonshotai/Moonlight-16B-A3B config.json. The balance loss strength
+    and the bias rate are DeepSeek-V3's (arXiv:2412.19437 Sec. 4.2). KV
+    chunks of 512: at 8192 tokens a 2048 chunk's score blocks add 1.2 GB to
+    the train step's peak on a v5e (AOT compile)."""
+    return ModelConfig(
+        name="moonlight-16b-a3b", family="moe", n_layers=27, d_model=2048,
+        vocab_size=163840, max_seq_len=8192, norm_eps=1e-5,
+        attention=AttentionConfig(n_heads=16, n_kv_heads=16, head_dim=192,
+                                  kind="mla", rope_theta=50000.0,
+                                  kv_lora_rank=512, rope_head_dim=64,
+                                  v_head_dim=128, kv_chunk=512),
+        ffn=moe_ffn(n_experts=64, expert_size=1408, k=6, activation="silu",
+                    selector_activation="sigmoid", renormalize=True,
+                    glu_experts=True, n_shared_experts=2,
+                    router_experts=64, routed_scale=2.446, bias_rate=1e-3,
+                    reg_kind="seq_balance", reg_gamma=1e-4, dispatch="sort"),
+        first_dense_layers=1, dense_d_ff=11264, cast_weights_per_layer=True,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Paper configs (Tab. 8 / Tab. 9)
 # ---------------------------------------------------------------------------
@@ -244,7 +269,12 @@ def reduced(name: str) -> ModelConfig:
         n_layers=min(cfg.n_layers, 3 if not cfg.pattern else len(cfg.pattern)),
         d_model=64, vocab_size=256, max_seq_len=512,
     )
-    if cfg.attention.n_heads:
+    if cfg.attention.kind == "mla":
+        kw["attention"] = AttentionConfig(
+            n_heads=4, n_kv_heads=4, head_dim=24, kind="mla",
+            rope_theta=cfg.attention.rope_theta, kv_lora_rank=32,
+            rope_head_dim=8, v_head_dim=16, kv_chunk=64)
+    elif cfg.attention.n_heads:
         kw["attention"] = AttentionConfig(
             n_heads=4, n_kv_heads=2 if cfg.attention.n_kv_heads < cfg.attention.n_heads else 4,
             head_dim=16, kind=cfg.attention.kind, window=32,
@@ -252,7 +282,17 @@ def reduced(name: str) -> ModelConfig:
     if cfg.ssm is not None:
         kw["ssm"] = SSMConfig(d_state=16, d_conv=4, expand=2, head_dim=16, chunk=32)
     f = cfg.ffn
-    if f.kind in ("sigma_moe", "switch", "sbase", "noisy_topk"):
+    if f.holds_share or f.bias_rate:
+        # the DeepSeek-V3 router: 16 experts, top-4, every setting but the
+        # sizes kept; the held share is set by the caller (n_experts)
+        kw["ffn"] = moe_ffn(16, 32, 4, router_experts=16,
+                            **{k: getattr(f, k) for k in (
+                                "activation", "selector_activation",
+                                "renormalize", "glu_experts",
+                                "n_shared_experts", "routed_scale",
+                                "bias_rate", "reg_kind", "reg_gamma",
+                                "dispatch")})
+    elif f.kind in ("sigma_moe", "switch", "sbase", "noisy_topk"):
         # dispatch="sort": dropless, so decode == full forward bit-for-bit in tests
         # (capacity-based paths legitimately drop different tokens per call shape).
         kw["ffn"] = moe_ffn(4, 32, min(f.k, 2),
@@ -271,6 +311,8 @@ def reduced(name: str) -> ModelConfig:
         kw["n_vision_tokens"] = 8
     if cfg.xl_memory:
         kw["xl_memory"] = 32
+    if cfg.first_dense_layers:
+        kw["dense_d_ff"] = 96
     return cfg.override(**kw)
 
 

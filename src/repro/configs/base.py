@@ -60,7 +60,7 @@ class FFNConfig:
     renormalize: bool = False          # re-normalize scores after top-K
     expert_dropout: float = 0.0        # delta (Eq. 22)
     reg_gamma: float = 0.0             # entropy reg strength (Eq. 21)
-    reg_kind: str = "entropy"          # entropy | switch | cv | none
+    reg_kind: str = "entropy"          # entropy | switch | cv | seq_balance | none
     capacity_factor: float = 1.25      # mu, for capacity-based dispatch
     dispatch: str = "einsum"           # einsum | sort  (sort == CVMM path)
     impl: str = "auto"                 # kernel lowering, see FFN_IMPLS
@@ -68,6 +68,18 @@ class FFNConfig:
     n_shared_experts: int = 0          # llama4-style always-on shared expert
     glu_experts: bool = False          # experts use GLU (for llama-family MoE)
     sinkhorn_iters: int = 8
+    # --- a chip's share of the experts (expert parallelism) ---
+    # The router scores ``router_experts`` experts (0: n_experts); this layer
+    # holds the n_experts of them from ``expert_offset`` on and computes only
+    # the (token, expert) rows routed to those. router_experts == n_experts
+    # (every config that holds all its experts) is the plain layer.
+    router_experts: int = 0
+    expert_offset: int = 0
+    routed_scale: float = 1.0          # gates times this (DeepSeek-V3 2.446)
+    # DeepSeek-V3 auxiliary-loss-free balancing: a per-expert bias added to the
+    # scores for choosing only, moved by bias_rate * sign(mean load - load)
+    # after each step (runtime/steps.py). 0 = no bias.
+    bias_rate: float = 0.0
     noise_std: float = 1.0             # noisy_topk
     # --- top-K activation (Sec 3.1) ---
     topk_k: int = 0
@@ -77,6 +89,16 @@ class FFNConfig:
     n_subkeys: int = 0                 # sqrt(d_ff); n_values = n_subkeys**2
     n_candidates: int = 0              # C: two-stage top-C per sub-key half
     #                                    (0 => C = pkm_knn, the minimum legal C)
+
+    @property
+    def n_router(self) -> int:
+        """Experts the router scores (all experts of the layer)."""
+        return self.router_experts or self.n_experts
+
+    @property
+    def holds_share(self) -> bool:
+        """True when this layer holds only some of the experts it routes to."""
+        return self.n_router != self.n_experts
 
     @property
     def n_values(self) -> int:
@@ -99,6 +121,11 @@ class FFNConfig:
         assert self.impl in FFN_IMPLS, self.impl
         if self.kind in ("sigma_moe", "switch", "sbase", "noisy_topk"):
             assert self.n_experts > 0 and self.expert_size > 0 and self.k > 0
+            assert 0 <= self.expert_offset and \
+                self.expert_offset + self.n_experts <= self.n_router, (
+                    f"held experts [{self.expert_offset}, "
+                    f"{self.expert_offset + self.n_experts}) outside the "
+                    f"router's {self.n_router}")
         if self.kind == "pkm":
             assert self.n_subkeys > 1
             # d_ff, when set for parameter accounting, must agree with the
@@ -140,16 +167,28 @@ class AttentionConfig:
     n_kv_heads: int = 0                # GQA; == n_heads for MHA
     head_dim: int = 0
     rope_theta: float = 10000.0
-    kind: str = "global"               # global | local (sliding window) | xl_rel
+    kind: str = "global"               # global | local (sliding window) | xl_rel | mla
     window: int = 0                    # sliding-window size for kind=local
     causal: bool = True
     qk_norm: bool = False
     softmax_scale: Optional[float] = None
     kv_chunk: int = 2048               # flash-attention KV chunk (pure-JAX path)
+    # Multi-head latent attention (kind="mla", DeepSeek-V2 Sec. 2.1): keys and
+    # values come up from a kv_lora_rank latent; head_dim is the query/key
+    # width, of which the last rope_head_dim carry RoPE (the key part shared by
+    # all heads); v_head_dim is the value width.
+    kv_lora_rank: int = 0
+    rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     @property
     def q_dim(self) -> int:
         return self.n_heads * self.head_dim
+
+    @property
+    def o_dim(self) -> int:
+        """Width of the heads' concatenated outputs (input of W_o)."""
+        return self.n_heads * (self.v_head_dim or self.head_dim)
 
     @property
     def kv_dim(self) -> int:
@@ -215,6 +254,15 @@ class ModelConfig:
     logit_softcap: float = 0.0
     # sub-quadratic? (decides long_500k applicability)
     subquadratic: bool = False
+    # leading layers whose FFN is a dense SiLU-GLU of width dense_d_ff in place
+    # of cfg.ffn (DeepSeek's first_k_dense_replace)
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
+    # cast each scanned layer's weights to the compute dtype inside the layer
+    # loop: otherwise XLA hoists one compute-dtype copy of the whole stack out
+    # of the forward and backward loops, alive through the backward (1 GB at
+    # the moonlight cut's 5 MoE layers)
+    cast_weights_per_layer: bool = False
 
     # ---- derived ----
     @property
@@ -226,6 +274,11 @@ class ModelConfig:
 
     def with_ffn(self, ffn: FFNConfig) -> "ModelConfig":
         return dataclasses.replace(self, ffn=ffn)
+
+    def dense_ffn(self) -> FFNConfig:
+        """The FFN of the leading dense layers."""
+        return FFNConfig(kind="glu", d_ff=self.dense_d_ff,
+                         activation=self.ffn.activation)
 
     def layer_pattern(self) -> List[BlockSpecEntry]:
         """Expanded per-layer pattern of length n_layers."""
@@ -260,14 +313,22 @@ class ModelConfig:
             return p, active
         # MoE family
         per_expert = (3 if f.glu_experts else 2) * d * f.expert_size
-        p = f.n_experts * per_expert + f.n_experts * d           # + router
+        p = f.n_experts * per_expert + f.n_router * d            # + router
         p += f.n_shared_experts * per_expert
-        active = (f.k + f.n_shared_experts) * per_expert + f.n_experts * d
+        # a held share computes the k routed rows' held part on average
+        k = f.k * f.n_experts / f.n_router
+        active = int((k + f.n_shared_experts) * per_expert + f.n_router * d)
         return p, active
 
     def attn_params(self) -> int:
         a = self.attention
         d = self.d_model
+        if a.kind == "mla":
+            nope = a.head_dim - a.rope_head_dim
+            return (d * a.q_dim + d * (a.kv_lora_rank + a.rope_head_dim)
+                    + a.kv_lora_rank
+                    + a.kv_lora_rank * a.n_heads * (nope + a.v_head_dim)
+                    + a.o_dim * d)
         p = d * a.q_dim + 2 * d * a.kv_dim + a.q_dim * d
         if a.kind == "xl_rel":
             # Transformer-XL: relative-position projection W_r (+ small u/v biases).
@@ -296,7 +357,7 @@ class ModelConfig:
         body_active = 0
         shared_attn_counted = False
         shared_ffn_counted = False
-        for entry in self.layer_pattern():
+        for li, entry in enumerate(self.layer_pattern()):
             if entry.mixer == "attn":
                 body_total += self.attn_params()
                 body_active += self.attn_params()
@@ -308,7 +369,11 @@ class ModelConfig:
             elif entry.mixer == "ssm":
                 body_total += self.ssm_params()
                 body_active += self.ssm_params()
-            if entry.ffn == "ffn":
+            if entry.ffn == "ffn" and li < self.first_dense_layers:
+                t, a = self.ffn_params(self.dense_ffn())
+                body_total += t
+                body_active += a
+            elif entry.ffn == "ffn":
                 t, a = self.ffn_params()
                 body_total += t
                 body_active += a

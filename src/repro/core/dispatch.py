@@ -194,6 +194,13 @@ def _sort_path(params: Dict, xf: jax.Array, cfg: FFNConfig,
     grouped matmul where row-groups share an expert matrix; 4. scatter-add
     results back per token, weighted by the gates.
 
+    A layer that holds a share of the experts (``cfg.holds_share``) gets
+    ``info`` with local expert ids and the id ``e`` on rows routed to experts
+    held elsewhere (``moe.held_share``): those rows join no group and get no
+    slot, so no kernel gathers or scatters them. The plan's static row bound
+    stays N*K, so every row a token could route here fits (nothing drops);
+    the tiles past the held rows are slack, zero rows through the grid.
+
     Under an active mesh the whole pipeline runs REPLICATED inside a
     ``shard_map`` (every device computes the full layer on gathered inputs):
     GSPMD cannot partition the Mosaic kernels at all. The sort path is the
@@ -248,7 +255,8 @@ def _sort_local(params: Dict, xf: jax.Array, cfg: FFNConfig,
         w1 = params["we1"].astype(xf.dtype)
         w2 = params["we2"].astype(xf.dtype)
         w1g = params["we1g"].astype(xf.dtype) if cfg.glu_experts else None
-        plan = kops.make_moe_plan(info.idx, info.gates, n, e)
+        plan = kops.make_moe_plan(info.idx, info.gates, n, e,
+                                  drop_unheld=cfg.holds_share)
         if kplan.rung == "pallas_fused":
             return kops.moe_mlp_fused(
                 xf, plan, w1, w2, w1g, activation=cfg.activation,

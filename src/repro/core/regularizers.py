@@ -41,7 +41,23 @@ def cv_reg(info: SelectionInfo, n_valid: int) -> jax.Array:
     return var / (mean * mean + 1e-9)
 
 
+def seq_balance_reg(info: SelectionInfo, n_valid: int,
+                    n_seq: int = 1) -> jax.Array:
+    """DeepSeek-V3's sequence-wise balance loss (Eqs. 17-20): per sequence of
+    T tokens, sum_e f[e] P[e] with f[e] = E / (K T) * (tokens choosing e) and
+    P[e] the mean over tokens of the scores normalized over experts; the mean
+    over the batch's ``n_seq`` sequences."""
+    k = info.idx.shape[-1]
+    s = info.sel.astype(jnp.float32)[:, :n_valid].reshape(n_seq, -1, n_valid)
+    p = jnp.mean(s / jnp.sum(s, axis=-1, keepdims=True), axis=1)
+    chosen = jnp.sum(jax.nn.one_hot(info.idx, n_valid, dtype=jnp.float32),
+                     axis=1).reshape(n_seq, -1, n_valid)
+    f = jnp.sum(chosen, axis=1) * (n_valid / (k * chosen.shape[1]))
+    return jnp.mean(jnp.sum(f * p, axis=-1))
+
+
 REGULARIZERS = {"entropy": entropy_reg, "switch": switch_reg, "cv": cv_reg,
+                "seq_balance": seq_balance_reg,
                 "none": lambda info, n_valid: jnp.float32(0.0)}
 
 

@@ -92,12 +92,15 @@ def expert_dropout_mask(rng: jax.Array, n_experts: int, rate: float) -> jax.Arra
 def select_experts(logits: jax.Array, cfg: FFNConfig, *,
                    rng: Optional[jax.Array] = None, train: bool = False,
                    noise_logits: Optional[jax.Array] = None,
-                   n_valid_experts: Optional[int] = None) -> SelectionInfo:
+                   n_valid_experts: Optional[int] = None,
+                   bias: Optional[jax.Array] = None) -> SelectionInfo:
     """Dispatch over the paper's selector variants.
 
     logits: (N, E_padded) = x @ W3.
     noise_logits: (N, E) = x @ W4, only for the Shazeer noisy-top-K variant.
     n_valid_experts: real expert count; experts >= this are padding (masked out).
+    bias: (E,) DeepSeek-V3 selection bias: the top-K is taken over sel + bias,
+    the gates are the chosen experts' sel alone.
     """
     n, e = logits.shape
     k = cfg.k
@@ -132,9 +135,15 @@ def select_experts(logits: jax.Array, cfg: FFNConfig, *,
         # Footnote 4: renormalizing after top-K == top-K on logits before softmax.
         gates, idx = norm_topk(sel, k)
     else:
-        gates, idx = jax.lax.top_k(sel, k)
+        if bias is None:
+            gates, idx = jax.lax.top_k(sel, k)
+        else:
+            _, idx = jax.lax.top_k(sel + bias.astype(sel.dtype), k)
+            gates = jnp.take_along_axis(sel, idx, axis=-1)
         if cfg.renormalize:
             gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-9)
+    if cfg.routed_scale != 1.0:
+        gates = gates * cfg.routed_scale
 
     return SelectionInfo(probs=probs, sel=sel, idx=idx, gates=gates)
 

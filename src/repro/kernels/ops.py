@@ -195,11 +195,14 @@ def _plan_runs(row_src: jax.Array, n_rows: int):
 
 
 def make_moe_plan(idx: jax.Array, gates: jax.Array, n_tokens: int,
-                  n_experts: int) -> CvmmPlan:
+                  n_experts: int, drop_unheld: bool = False) -> CvmmPlan:
     """Build the CvmmPlan for one MoE call from the routing selection.
 
     idx (N, K) int expert ids, gates (N, K) gate values. Differentiable in
-    ``gates`` (the scatter into ``gate_tiles`` is transparent to autodiff)."""
+    ``gates`` (the scatter into ``gate_tiles`` is transparent to autodiff).
+    ``drop_unheld``: ids may also be ``n_experts``, a row routed to an expert
+    this layer does not hold; such rows sort last, join no group and get no
+    slot (row_src keeps the sentinel there)."""
     k = idx.shape[-1]
     e_flat = idx.reshape(-1).astype(jnp.int32)
     g_flat = gates.reshape(-1)
@@ -208,6 +211,8 @@ def make_moe_plan(idx: jax.Array, gates: jax.Array, n_tokens: int,
     group_sizes = jnp.bincount(e_flat, length=n_experts).astype(jnp.int32)
     new_pos, tile_expert, m_pad = _tile_layout(group_sizes, e_flat.shape[0],
                                                n_experts)
+    if drop_unheld:
+        new_pos = jnp.where(e_flat[perm] < n_experts, new_pos, m_pad)
     row_src = jnp.full((m_pad,), n_tokens, jnp.int32).at[new_pos].set(tok[perm])
     run_start, run_len, run_off = _plan_runs(row_src, n_tokens)
     gate_pad = jnp.zeros((m_pad,), jnp.float32).at[new_pos].set(

@@ -177,13 +177,16 @@ def run(argv=None) -> TrainRun:
                         state, metrics = step_fn(state, batch, rng)
                     with span("train.readback"):
                         m = {k: float(metrics[k])
-                             for k in ("loss", "grad_norm", "lr")}
+                             for k in ("loss", "grad_norm", "lr",
+                                       "moe_rows_held") if k in metrics}
                     history.append(dict(m, step=step))
                     dt = mon.stop(step)
                     if step % args.log_every == 0 or step == args.steps - 1:
+                        held = (f" rows_held {m['moe_rows_held']:.0f}"
+                                if "moe_rows_held" in m else "")
                         print(f"step {step:5d} loss {m['loss']:.4f} "
-                              f"lr {m['lr']:.2e} gnorm {m['grad_norm']:.3f} "
-                              f"{dt:.3f}s", flush=True)
+                              f"lr {m['lr']:.2e} gnorm {m['grad_norm']:.3f}"
+                              f"{held} {dt:.3f}s", flush=True)
                     if args.ckpt_every and (step + 1) % args.ckpt_every == 0:
                         with span("train.checkpoint"):
                             mgr.save(step + 1, state,

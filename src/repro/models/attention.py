@@ -9,6 +9,7 @@ XLA also compiles well (it is the same loop structure the kernel uses).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -30,6 +31,8 @@ from .layers import apply_rope, rms_norm_simple, sinusoid_positions
 def init_attention(key, cfg: ModelConfig, dtype=jnp.float32) -> Dict:
     a = cfg.attention
     d = cfg.d_model
+    if a.kind == "mla":
+        return init_mla(key, cfg, dtype)
     kq, kk, kv, ko, kr = jax.random.split(key, 5)
     std = (d ** -0.5)
     p = {
@@ -46,6 +49,26 @@ def init_attention(key, cfg: ModelConfig, dtype=jnp.float32) -> Dict:
         p["u_bias"] = jnp.zeros((a.n_heads, a.head_dim), dtype)
         p["v_bias"] = jnp.zeros((a.n_heads, a.head_dim), dtype)
     return p
+
+
+def init_mla(key, cfg: ModelConfig, dtype=jnp.float32) -> Dict:
+    """Multi-head latent attention (DeepSeek-V2 Sec. 2.1) without a query
+    latent: W_q (d, H*(nope+rope)); W_kv_a (d, rank+rope) down to the latent
+    and the shared RoPE key; the latent's RMSNorm; W_kv_b (rank,
+    H*(nope+v)) up to per-head keys and values; W_o (H*v, d)."""
+    a = cfg.attention
+    d, r = cfg.d_model, a.kv_lora_rank
+    nope = a.head_dim - a.rope_head_dim
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "wq": d ** -0.5 * jax.random.normal(kq, (d, a.q_dim), dtype),
+        "wkv_a": d ** -0.5 * jax.random.normal(
+            ka, (d, r + a.rope_head_dim), dtype),
+        "kv_norm": {"scale": jnp.ones((r,), dtype)},
+        "wkv_b": r ** -0.5 * jax.random.normal(
+            kb, (r, a.n_heads * (nope + a.v_head_dim)), dtype),
+        "wo": a.o_dim ** -0.5 * jax.random.normal(ko, (a.o_dim, d), dtype),
+    }
 
 
 def _split_heads(x, n_heads, head_dim):
@@ -68,13 +91,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool, window: int = 0, scale: float,
                     q_offset: int = 0, kv_chunk: int = 2048,
                     kv_len: Optional[jax.Array] = None) -> jax.Array:
-    """q (B,Sq,H,D), k/v (B,Sk,KV,D) -> (B,Sq,H,D); never materializes (Sq,Sk).
+    """q (B,Sq,H,D), k (B,Sk,KV,D), v (B,Sk,KV,Dv) -> (B,Sq,H,Dv); never
+    materializes (Sq,Sk).
 
     q_offset: absolute position of q[0] relative to k[0] (for caches/memory).
     kv_len: optional (B,) valid KV lengths (decode against a partially-filled cache).
     """
     b, sq, h, dh = q.shape
-    sk, kvh = k.shape[1], k.shape[2]
+    sk, kvh, dv = k.shape[1], k.shape[2], v.shape[-1]
     grp = h // kvh
     qg = q.reshape(b, sq, kvh, grp, dh)
     nchunks = -(-sk // kv_chunk)
@@ -84,7 +108,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         k = jnp.pad(k, pad)
         v = jnp.pad(v, pad)
     kc = k.reshape(b, nchunks, kv_chunk, kvh, dh)
-    vc = v.reshape(b, nchunks, kv_chunk, kvh, dh)
+    vc = v.reshape(b, nchunks, kv_chunk, kvh, dv)
 
     q_pos = q_offset + jnp.arange(sq)
 
@@ -117,7 +141,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     m0 = jnp.full((b, kvh, grp, sq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, kvh, grp, sq), jnp.float32)
-    a0 = jnp.zeros((b, kvh, grp, sq, dh), jnp.float32)
+    a0 = jnp.zeros((b, kvh, grp, sq, dv), jnp.float32)
     # checkpoint per chunk: backward recomputes the (sq, chunk) probability block
     # instead of storing one per scan step (which would be O(Sq*Sk) memory -- the
     # exact failure mode flash attention exists to avoid).
@@ -126,8 +150,123 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         body, (m0, l0, a0),
         (jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0), jnp.arange(nchunks)))
     out = acc / jnp.maximum(l[..., None], 1e-20)
-    out = jnp.moveaxis(out, 3, 1).reshape(b, sq, h, dh)      # (B,Sq,KV,Grp,D)->(B,Sq,H,D)
+    out = jnp.moveaxis(out, 3, 1).reshape(b, sq, h, dv)      # (B,Sq,KV,Grp,D)->(B,Sq,H,D)
     return out.astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Causal chunked attention with a recomputing backward (training)
+# ---------------------------------------------------------------------------
+# ``flash_attention`` is differentiated through its scan: the backward keeps
+# every chunk's (m, l, acc) carry, O(Sk / chunk) copies of the (Sq, Dv)
+# accumulator (1.1 GB per layer at 8k tokens, 16 heads of 128, chunks of
+# 512). ``lean_attention`` saves only q, k, v, the output and the row
+# log-sum-exp, and its backward recomputes each chunk's probabilities from
+# them (FlashAttention-2's backward): dV += P^T dO, dP = dO V^T,
+# dS = P * (dP - rowsum(dO * O)), dQ += dS K, dK = dS^T Q. Products take
+# the operands' dtype with float32 accumulation.
+
+def _lean_chunks(k, v, kv_chunk):
+    b, sk, kvh, dh = k.shape
+    n = -(-sk // kv_chunk)
+    pad = [(0, 0), (0, n * kv_chunk - sk), (0, 0), (0, 0)]
+    kc = jnp.pad(k, pad).reshape(b, n, kv_chunk, kvh, dh)
+    vc = jnp.pad(v, pad).reshape(b, n, kv_chunk, kvh, v.shape[-1])
+    return jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0), n
+
+
+def _lean_scores(qg, kb, cidx, *, sk, causal, scale):
+    """One chunk's scaled scores (B,KV,G,Sq,C) in float32, -inf where
+    masked (after the causal diagonal, or past the keys)."""
+    sq, c = qg.shape[1], kb.shape[1]
+    k_pos = cidx * c + jnp.arange(c)
+    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, kb,
+                   preferred_element_type=jnp.float32) * scale
+    mask = (k_pos < sk)[None, :]
+    if causal:
+        mask = mask & (jnp.arange(sq)[:, None] >= k_pos[None, :])
+    return jnp.where(mask, s, -jnp.inf)
+
+
+def _lean_forward(q, k, v, causal, scale, kv_chunk):
+    b, sq, h, dh = q.shape
+    sk, kvh, dv = k.shape[1], k.shape[2], v.shape[-1]
+    qg = q.reshape(b, sq, kvh, h // kvh, dh)
+    kc, vc, n = _lean_chunks(k, v, kv_chunk)
+
+    def body(carry, xs):
+        m, l, acc = carry
+        kb, vb, cidx = xs
+        s = _lean_scores(qg, kb, cidx, sk=sk, causal=causal, scale=scale)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+        p = jnp.exp(s - m_safe[..., None])
+        corr = jnp.exp(m - m_safe)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bkgqs,bskd->bkgqd", p.astype(v.dtype), vb,
+            preferred_element_type=jnp.float32)
+        return (m_new, l * corr + jnp.sum(p, axis=-1), acc), None
+
+    shape = (b, kvh, h // kvh, sq)
+    (m, l, acc), _ = jax.lax.scan(
+        body, (jnp.full(shape, -jnp.inf, jnp.float32),
+               jnp.zeros(shape, jnp.float32),
+               jnp.zeros(shape + (dv,), jnp.float32)),
+        (kc, vc, jnp.arange(n)))
+    l = jnp.maximum(l, 1e-30)
+    out = jnp.moveaxis(acc / l[..., None], 3, 1).reshape(b, sq, h, dv)
+    return out.astype(q.dtype), m + jnp.log(l)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def lean_attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
+                   scale: float, kv_chunk: int) -> jax.Array:
+    """Full-sequence attention for training: q (B,S,H,D), k (B,S,KV,D),
+    v (B,S,KV,Dv) -> (B,S,H,Dv), causal or not, over KV chunks of
+    ``kv_chunk``; its backward recomputes the chunks (see above)."""
+    return _lean_forward(q, k, v, causal, scale, kv_chunk)[0]
+
+
+def _lean_fwd(q, k, v, causal, scale, kv_chunk):
+    out, lse = _lean_forward(q, k, v, causal, scale, kv_chunk)
+    return out, (q, k, v, out, lse)
+
+
+def _lean_bwd(causal, scale, kv_chunk, res, dout):
+    q, k, v, out, lse = res
+    b, sq, h, dh = q.shape
+    sk, kvh, dv = k.shape[1], k.shape[2], v.shape[-1]
+    grp = h // kvh
+    qg = q.reshape(b, sq, kvh, grp, dh)
+    dog = dout.reshape(b, sq, kvh, grp, dv).astype(q.dtype)
+    delta = jnp.einsum("bqkgd,bqkgd->bkgq", dog.astype(jnp.float32),
+                       out.reshape(b, sq, kvh, grp, dv).astype(jnp.float32))
+    kc, vc, n = _lean_chunks(k, v, kv_chunk)
+
+    def body(dq, xs):
+        kb, vb, cidx = xs
+        s = _lean_scores(qg, kb, cidx, sk=sk, causal=causal, scale=scale)
+        p = jnp.exp(s - lse[..., None])
+        dvb = jnp.einsum("bkgqs,bqkgd->bskd", p.astype(q.dtype), dog,
+                         preferred_element_type=jnp.float32)
+        dp = jnp.einsum("bqkgd,bskd->bkgqs", dog, vb,
+                        preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta[..., None]) * scale).astype(q.dtype)
+        dq = dq + jnp.einsum("bkgqs,bskd->bqkgd", ds, kb,
+                             preferred_element_type=jnp.float32)
+        dkb = jnp.einsum("bkgqs,bqkgd->bskd", ds, qg,
+                         preferred_element_type=jnp.float32)
+        return dq, (dkb.astype(k.dtype), dvb.astype(v.dtype))
+
+    dq, (dk, dvs) = jax.lax.scan(
+        body, jnp.zeros(qg.shape, jnp.float32), (kc, vc, jnp.arange(n)))
+
+    def unchunk(x):
+        return jnp.moveaxis(x, 0, 1).reshape(b, -1, kvh, x.shape[-1])[:, :sk]
+    return dq.reshape(q.shape).astype(q.dtype), unchunk(dk), unchunk(dvs)
+
+
+lean_attention.defvjp(_lean_fwd, _lean_bwd)
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -290,6 +429,37 @@ def xl_attention(params: Dict, q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2 Sec. 2.1)
+# ---------------------------------------------------------------------------
+
+@jax.named_scope("mla_latent")
+def mla_qkv(params: Dict, x: jax.Array, cfg: ModelConfig,
+            positions: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Queries, keys and values of MLA: the latent down-projection, its
+    RMSNorm, the up-projection and the decoupled RoPE. q, k (B,S,H,nope+rope)
+    with the rope part last (the key's shared by every head), v (B,S,H,v)."""
+    a = cfg.attention
+    b, s, _ = x.shape
+    r, rope = a.kv_lora_rank, a.rope_head_dim
+    nope = a.head_dim - rope
+    q = _split_heads(jnp.einsum("bsd,dq->bsq", x, params["wq"].astype(x.dtype)),
+                     a.n_heads, a.head_dim)
+    kv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"].astype(x.dtype))
+    c = rms_norm_simple(kv[..., :r], params["kv_norm"]["scale"], cfg.norm_eps)
+    k_pe = apply_rope(kv[..., None, r:], positions, a.rope_theta)
+    kvb = _split_heads(
+        jnp.einsum("bsr,rq->bsq", c, params["wkv_b"].astype(x.dtype)),
+        a.n_heads, nope + a.v_head_dim)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope(q[..., nope:], positions, a.rope_theta)],
+        axis=-1)
+    k = jnp.concatenate(
+        [kvb[..., :nope], jnp.broadcast_to(k_pe, (b, s, a.n_heads, rope))],
+        axis=-1)
+    return q, k, kvb[..., nope:]
+
+
+# ---------------------------------------------------------------------------
 # Full block-level apply
 # ---------------------------------------------------------------------------
 
@@ -317,6 +487,17 @@ def apply_attention(params: Dict, x: jax.Array, cfg: ModelConfig, *,
     kind = kind or a.kind
     b, s, d = x.shape
     scale = a.softmax_scale if a.softmax_scale else a.head_dim ** -0.5
+    if kind == "mla":
+        if cache is not None or memory is not None or cross_kv is not None:
+            raise NotImplementedError("mla attention runs full sequences "
+                                      "only (no KV cache, memory or cross)")
+        if positions is None:
+            positions = jnp.arange(s)
+        q, k, v = mla_qkv(params, x, cfg, positions)
+        out = lean_attention(q, k, v, a.causal, scale, a.kv_chunk)
+        y = jnp.einsum("bsq,qd->bsd", out.reshape(b, s, a.o_dim),
+                       params["wo"].astype(x.dtype))
+        return y, None
 
     q = _split_heads(jnp.einsum("bsd,dq->bsq", x, params["wq"].astype(x.dtype)),
                      a.n_heads, a.head_dim)

@@ -162,7 +162,32 @@ class LM:
         loss = ce + aux["moe_reg"]
         metrics = {"ce": ce, "moe_reg": aux["moe_reg"],
                    "moe_dropped": aux["moe_dropped"], "tokens": n_tok}
+        # selection-bias layers: their loads (for the step's bias update)
+        # and the rows their held experts computed
+        metrics.update({k: aux[k] for k in ("loads", "moe_rows_held")
+                        if k in aux})
         return loss, (metrics if mems is None else (metrics, new_mems))
+
+    def update_selection_bias(self, new_params: Dict, params: Dict,
+                              loads: Dict) -> Dict:
+        """``new_params`` with each MoE layer's selection bias moved from its
+        value in ``params`` by the layer's expert loads (``loads`` as
+        ``forward`` reports them; core/moe.update_selection_bias)."""
+        from ..core.moe import update_selection_bias
+        segs = []
+        for new, old, seg_loads in zip(new_params["stack"]["segments"],
+                                       params["stack"]["segments"],
+                                       loads["segments"]):
+            new = dict(new)
+            for name, load in seg_loads.items():
+                ffn = dict(new[name]["ffn"])
+                ffn["router_bias"] = {"bias": update_selection_bias(
+                    old[name]["ffn"]["router_bias"]["bias"], load,
+                    self.cfg.ffn.bias_rate)}
+                new[name] = dict(new[name], ffn=ffn)
+            segs.append(new)
+        stack = dict(new_params["stack"], segments=segs)
+        return dict(new_params, stack=stack)
 
     def logits(self, params, tokens: jax.Array) -> jax.Array:
         """(B, S, V) next-token logits of the contiguous full-sequence
@@ -222,6 +247,11 @@ class LM:
                 " (per-request offsets need position-free embeddings)")
         if cfg.n_vision_tokens:
             raise NotImplementedError("paged serving: vision prefix unsupported")
+        if cfg.attention.kind == "mla":
+            raise NotImplementedError(
+                "paged serving: mla attention unsupported (a paged cache of "
+                "the compressed latent and the shared RoPE key does not "
+                "exist yet)")
 
     def init_paged_cache(self, n_pages: int, page_size: int) -> Dict:
         """Paged KV pool shared by all requests; page 0 is the reserved

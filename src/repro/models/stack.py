@@ -42,6 +42,12 @@ def plan_segments(cfg: ModelConfig, n_layers: Optional[int] = None) -> List[Segm
     pattern = cfg.pattern or (BlockSpecEntry(mixer="attn", ffn="ffn"),)
     p = len(pattern)
     segs = []
+    if cfg.first_dense_layers and n_layers is None:
+        # leading dense layers: a segment of their own before the pattern's
+        k = cfg.first_dense_layers
+        segs.append(Segment((BlockSpecEntry(mixer="attn", ffn="dense_ffn"),),
+                            k))
+        n -= k
     if n // p:
         segs.append(Segment(tuple(pattern), n // p))
     if n % p:
@@ -71,11 +77,15 @@ def init_block(key, cfg: ModelConfig, entry: BlockSpecEntry, dtype,
     if cross:
         p["norm_x"] = init_norm(cfg, cfg.d_model, dtype)
         p["cross"] = init_attention(keys[2], cfg, dtype)
-    if entry.ffn == "ffn":
+    if entry.ffn in ("ffn", "dense_ffn"):
         p["norm2"] = init_norm(cfg, cfg.d_model, dtype)
-        p["ffn"] = init_ffn(keys[1], cfg.d_model, cfg.ffn, cfg.n_layers, dtype,
-                            ep_degree)
+        p["ffn"] = init_ffn(keys[1], cfg.d_model, _block_ffn(cfg, entry),
+                            cfg.n_layers, dtype, ep_degree)
     return p
+
+
+def _block_ffn(cfg: ModelConfig, entry: BlockSpecEntry):
+    return cfg.dense_ffn() if entry.ffn == "dense_ffn" else cfg.ffn
 
 
 def init_shared_block(key, cfg: ModelConfig, dtype) -> Dict:
@@ -100,7 +110,9 @@ def apply_block(params: Dict, shared: Optional[Dict], x: jax.Array,
                 block_table: Optional[jax.Array] = None,
                 seq_lens: Optional[jax.Array] = None,
                 sp: bool = False) -> Tuple[jax.Array, Dict, Optional[Dict], Optional[jax.Array]]:
-    """Pre-norm residual block. Returns (x, aux, new_cache, new_memory)."""
+    """Pre-norm residual block. Returns (x, aux, new_cache, new_memory).
+    An MoE layer with a selection bias adds its per-expert ``load`` and the
+    ``moe_rows_held`` count to aux."""
     aux = {"moe_reg": jnp.float32(0.0), "moe_dropped": jnp.float32(0.0)}
     new_cache = {}
     new_memory = None
@@ -164,8 +176,10 @@ def apply_block(params: Dict, shared: Optional[Dict], x: jax.Array,
         fp = shared["ffn"] if ffn_kind == "shared_ffn" else params["ffn"]
         fn = shared["norm2"] if ffn_kind == "shared_ffn" else params["norm2"]
         h = norm(fn, x)
-        y, faux = apply_ffn(fp, h, cfg.ffn, rng=r2, train=train)
+        y, faux = apply_ffn(fp, h, _block_ffn(cfg, entry), rng=r2, train=train)
         aux = {k: aux[k] + faux.get(k, 0.0) for k in aux}
+        aux.update({k: faux[k] for k in ("load", "moe_rows_held")
+                    if k in faux})
         x = residual(x, r2, y)
     return x, aux, (new_cache or None), new_memory
 
@@ -267,6 +281,11 @@ def init_paged_stack_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     return cache
 
 
+def _add_aux(a: Dict, b: Dict) -> Dict:
+    """Sum of two aux dicts, key by key; a key one of them lacks counts 0."""
+    return {k: a.get(k, 0.0) + b.get(k, 0.0) for k in {**a, **b}}
+
+
 def apply_stack(params: Dict, x: jax.Array, cfg: ModelConfig, *,
                 rng: Optional[jax.Array] = None, train: bool = False,
                 positions: Optional[jax.Array] = None,
@@ -278,12 +297,16 @@ def apply_stack(params: Dict, x: jax.Array, cfg: ModelConfig, *,
                 seq_lens: Optional[jax.Array] = None,
                 remat: str = "none", sp: bool = False,
                 n_layers: Optional[int] = None):
-    """Run all segments. Returns (x, aux, new_cache, new_mems)."""
+    """Run all segments. Returns (x, aux, new_cache, new_mems). Where MoE
+    layers report a per-expert load, aux["loads"] holds them in the layout of
+    the stack's parameters ({"segments": [{"e<i>": (repeats, E)}]}) and
+    aux["moe_rows_held"] their held rows, summed."""
     segs = plan_segments(cfg, n_layers)
     shared = params.get("shared")
     aux_tot = {"moe_reg": jnp.float32(0.0), "moe_dropped": jnp.float32(0.0)}
     new_cache = {"segments": []} if cache is not None else None
     new_mems_segs = [] if mems is not None else None
+    all_loads = []
     layer_offset = 0
 
     policy = {"none": None,
@@ -299,9 +322,17 @@ def apply_stack(params: Dict, x: jax.Array, cfg: ModelConfig, *,
 
         def body(x, xs, seg=seg, off=layer_offset):
             ep, ridx, cxs, mxs = xs
+            if cfg.cast_weights_per_layer and seg.repeats > 1:
+                # times exactly 1, but a 1 that depends on the loop index,
+                # so the cast that follows stays inside the loop
+                one = (ridx >= 0).astype(jnp.float32)
+                ep = jax.tree_util.tree_map(
+                    lambda a: a * one.astype(a.dtype)
+                    if jnp.issubdtype(a.dtype, jnp.floating) else a, ep)
             aux_acc = {"moe_reg": jnp.float32(0.0), "moe_dropped": jnp.float32(0.0)}
             new_c = {}
             new_m = {}
+            loads = {}
             for ei, entry in enumerate(seg.entries):
                 li = off + ridx * len(seg.entries) + ei
                 r = jax.random.fold_in(rng, li) if rng is not None else None
@@ -317,12 +348,14 @@ def apply_stack(params: Dict, x: jax.Array, cfg: ModelConfig, *,
                     block_table=block_table, seq_lens=seq_lens,
                     sp=sp)
                 x = xc
-                aux_acc = {k: aux_acc[k] + aux[k] for k in aux_acc}
+                if "load" in aux:
+                    loads[f"e{ei}"] = aux.pop("load")
+                aux_acc = _add_aux(aux_acc, aux)
                 if nc is not None:
                     new_c[f"e{ei}"] = nc
                 if nm is not None:
                     new_m[f"e{ei}"] = nm
-            return x, (aux_acc, new_c, new_m)
+            return x, (aux_acc, new_c, new_m, loads)
 
         if policy is not None:
             body = jax.checkpoint(body, policy=policy)
@@ -335,13 +368,15 @@ def apply_stack(params: Dict, x: jax.Array, cfg: ModelConfig, *,
                   if seg_cache is not None else None)
             m0 = (jax.tree_util.tree_map(lambda a: a[0], seg_mems)
                   if seg_mems is not None else None)
-            x, (aux, nc, nm) = body(x, (ep0, jnp.int32(0), c0, m0))
+            x, (aux, nc, nm, seg_loads) = body(x, (ep0, jnp.int32(0), c0, m0))
             nc = jax.tree_util.tree_map(lambda a: a[None], nc)
             nm = jax.tree_util.tree_map(lambda a: a[None], nm)
+            seg_loads = jax.tree_util.tree_map(lambda a: a[None], seg_loads)
         else:
-            x, (auxs, nc, nm) = jax.lax.scan(body, x, xs)
+            x, (auxs, nc, nm, seg_loads) = jax.lax.scan(body, x, xs)
             aux = jax.tree_util.tree_map(lambda a: jnp.sum(a, 0), auxs)
-        aux_tot = {k: aux_tot[k] + aux[k] for k in aux_tot}
+        aux_tot = _add_aux(aux_tot, aux)
+        all_loads.append(seg_loads)
         if new_cache is not None:
             new_cache["segments"].append(nc if nc else seg_cache)
         if new_mems_segs is not None:
@@ -349,6 +384,8 @@ def apply_stack(params: Dict, x: jax.Array, cfg: ModelConfig, *,
         layer_offset += seg.repeats * len(seg.entries)
 
     new_mems = {"segments": new_mems_segs} if new_mems_segs is not None else None
+    if any(all_loads):
+        aux_tot["loads"] = {"segments": all_loads}
     return x, aux_tot, new_cache, new_mems
 
 

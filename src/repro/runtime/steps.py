@@ -160,6 +160,8 @@ def make_train_step(model: LM, opt_cfg: OptimizerConfig,
         else:
             loss, metrics, new_mems, grads = compute_grads(params, batch, rng,
                                                            mems)
+        metrics = dict(metrics)
+        loads = metrics.pop("loads", None)
         with jax.named_scope("optimizer"):
             grads, gnorm = clip_by_global_norm(grads, opt_cfg.grad_clip)
             if "err" in state and not pod_tier:
@@ -169,6 +171,11 @@ def make_train_step(model: LM, opt_cfg: OptimizerConfig,
             lr = sched(state["opt"].step)
             new_params, new_opt = adamw_update(grads, state["opt"], params,
                                                opt_cfg, lr)
+            if loads is not None:
+                # MoE selection biases move with the step's loads, not by
+                # their (zero) gradient
+                new_params = model.update_selection_bias(new_params, params,
+                                                         loads)
         new_state["params"] = new_params
         new_state["opt"] = new_opt
         if new_mems is not None:

@@ -351,3 +351,74 @@ def test_train_step_compiles_on_a_mesh(v5e_2x2, arch, shape):
         r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target="tpu_custom_call"',
         text, re.M)}
     assert found == SORT_KERNELS | XL_REL_KERNELS
+
+
+# ---------------------------------------------------------------------------
+# Moonlight-16B-A3B: the benchmark's cut, and what it shares with wt103
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def moonlight_step(one_chip):
+    """The compiled train step of the benchmark's Moonlight cut (dense
+    layer + 5 MoE layers holding 8 of 64 experts, vocabulary 20480, published
+    widths) on one 2048-token row, as chipbench/drivers/train.py jits it."""
+    from repro.configs import OptimizerConfig, get_config
+    from repro.models import build_model
+    from repro.optim import adamw_init
+    from repro.runtime.steps import make_train_step
+
+    cfg = get_config("moonlight-16b-a3b").override(n_layers=6,
+                                                   vocab_size=20480)
+    cfg = cfg.with_ffn(dataclasses.replace(cfg.ffn, n_experts=8,
+                                           impl="pallas_fused"))
+    model = build_model(cfg, remat="full")
+    step = make_train_step(model, OptimizerConfig())
+    state = jax.eval_shape(lambda k: (lambda p: {
+        "params": p, "opt": adamw_init(p)})(model.init(k)),
+        jax.random.PRNGKey(0))
+    args = (state, {"tokens": jax.ShapeDtypeStruct((1, 2049), jnp.int32)},
+            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        args)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(step, donate_argnums=(0,)).lower(*args).compile() \
+            .as_text()
+    return re.findall(r'^\s*(?:ROOT )?%(\S+) = (.*?)metadata=\{op_name="([^"]*)"',
+                      text, re.M)
+
+
+def test_moonlight_step_runs_the_sort_kernels(moonlight_step):
+    found = {inst.split(".")[0] for inst, rhs, name in moonlight_step
+             if 'custom_call_target="tpu_custom_call"' in rhs
+             and "moe" in _scopes(name)}
+    assert found == SORT_KERNELS
+
+
+@pytest.mark.parametrize("layer,scope", [("attention", "mla_latent"),
+                                         ("moe", "router")])
+def test_moonlight_scopes_inside_their_layers(moonlight_step, layer, scope):
+    # forward and backward both, each named inside its layer
+    for backward in (False, True):
+        names = _names(moonlight_step, backward=backward)
+        inside = [s for s in names if scope in s]
+        assert inside
+        assert all(layer in s and s.index(layer) < s.index(scope)
+                   for s in inside)
+
+
+def test_wt103_step_lowers_to_the_same_ops_for_the_chip(v5e_2x2):
+    """The wt103-262m-moe step through the fused kernels, lowered for one
+    chip, has the ops it had before the held-share filter, selection bias
+    and routed scale existed (tests/lowered_ops.py; kernel bodies aside)."""
+    import json
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from lowered_ops import fingerprint, wt103_train_step_text
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "data", "wt103_train_step_v5e.json")) as f:
+        want = json.load(f)
+    got = fingerprint(wt103_train_step_text(v5e_2x2[0]))
+    assert got["ops"] == want["ops"]
+    assert got["sha256"] == want["sha256"]
