@@ -1,12 +1,16 @@
 #!/usr/bin/env python3
 """Bring the main paths up on a TPU and check what they compute.
 
-    python3 chip_smoke.py              # xl_rel, train, serve on one chip
+    python3 chip_smoke.py              # xl_rel, held, train, serve on one chip
     python3 chip_smoke.py --chips 4    # mesh training on four chips only
 
 xl_rel the shifted BD kernel of xl_rel attention against the XLA path it
        replaces, forward and VJP, at the training shapes and at one query,
        no memory, and every residue of the query count mod 8.
+held   the MoE kernels on one chip's share of Moonlight-16B-A3B's experts
+       (8 of 64 held, d_model 2048, GLU experts of 1408, top-6, 8192
+       tokens), stopping at the plan's live bound, against the same plan
+       with every tile live (bit for bit) and ragged_dot, forward and VJP.
 train  wt103-262m-moe as registered (paper Tab. 8/9: 18 layers, d_model 1024,
        32 experts of 128, k=4, xl_rel attention with 512 memory), batch 8 x
        seq 512, 6 steps through ``repro.launch.train`` on a 1x1 mesh, with
@@ -151,6 +155,114 @@ def xl_rel_phase(shapes=XL_REL_SHAPES, *, seed: int = 0,
               f" of the XLA path")
         worst = [max(worst[0], fwd), max(worst[1], vjp)]
     return tuple(worst)
+
+
+# ---------------------------------------------------------------------------
+# held: the live bound of a held-share plan
+# ---------------------------------------------------------------------------
+
+# One chip of 8-way expert parallelism over Moonlight-16B-A3B's routed
+# experts, as the benchmark's cell train-moonlight-s8k runs them.
+HELD_SHARE = dict(n_tokens=8192, d_model=2048, expert_size=1408,
+                  n_router=64, held=8, k=6)
+# Against ragged_dot: bfloat16 operands and outputs, float32 accumulation
+# on both paths; each output's relative L2 error may reach a few roundoffs
+# (2**-8) of the bfloat16 intermediates. A skipped live tile or a leaked
+# slack row is off by the rows' whole values.
+HELD_SHARE_RTOL = 2.0 ** -5
+
+
+def held_share_phase(*, n_tokens, d_model, expert_size, n_router, held, k,
+                     seed: int = 0, interpret: bool = False):
+    """The sort path's kernels on a held-share plan (``make_moe_plan(
+    drop_unheld=True)``: rows routed to experts held elsewhere get no slot),
+    stopping at the plan's live bound, against the same plan with every
+    tile live: the output and its VJP (dX, d(gate), dW1, dW1g, dW2) bit for
+    bit, and both within ``HELD_SHARE_RTOL`` of ragged_dot. Returns (live
+    tiles, grid tiles, largest relative error, seconds per forward and VJP
+    at the bound and with every tile live)."""
+    from repro.kernels import ops
+
+    dt = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    xf = jax.random.normal(ks[0], (n_tokens, d_model)).astype(dt)
+    idx = jax.lax.top_k(jax.random.normal(ks[1], (n_tokens, n_router)), k)[1]
+    here = idx < held
+    idx = jnp.where(here, idx, held)
+    gates = jnp.where(here, jax.random.uniform(ks[2], (n_tokens, k)), 0.0)
+    shape1, shape2 = (held, d_model, expert_size), (held, expert_size,
+                                                    d_model)
+    w1 = (jax.random.normal(ks[3], shape1) * d_model ** -0.5).astype(dt)
+    w1g = (jax.random.normal(ks[4], shape1) * d_model ** -0.5).astype(dt)
+    w2 = (jax.random.normal(ks[5], shape2) * expert_size ** -0.5).astype(dt)
+    cot = jax.random.normal(ks[6], (n_tokens, d_model)).astype(dt)
+    args = (xf, gates, w1, w1g, w2)
+
+    def with_vjp(mlp, *a):
+        y, vjp = jax.vjp(mlp, *a)
+        return (y,) + vjp(cot)
+
+    @jax.jit
+    def kernels(all_live, *a):
+        def mlp(xf, gates, w1, w1g, w2):
+            plan = ops.make_moe_plan(idx, gates, n_tokens, held,
+                                     drop_unheld=True)
+            plan = plan._replace(n_live=jnp.where(
+                all_live, plan.m_pad // ops.TM, plan.n_live))
+            return ops.moe_mlp_fused(xf, plan, w1, w2, w1g,
+                                     activation="silu", interpret=interpret)
+        return with_vjp(mlp, *a)
+
+    @jax.jit
+    def reference(*a):
+        # Rows held elsewhere (id ``held``) go to one extra expert of zero
+        # weights: ragged_dot leaves rows past its groups undefined, and
+        # on the chip they hold garbage that the VJP would carry into dx.
+        def mlp(xf, gates, w1, w1g, w2):
+            e_flat = idx.reshape(-1)
+            perm = jnp.argsort(e_flat, stable=True)
+            gs = jnp.bincount(e_flat, length=held + 1).astype(jnp.int32)
+            tok = jnp.repeat(jnp.arange(n_tokens), k)[perm]
+            xs = xf[tok]
+            w1, w1g, w2 = (jnp.concatenate([w, jnp.zeros_like(w[:1])])
+                           for w in (w1, w1g, w2))
+            u = (jax.nn.silu(jax.lax.ragged_dot(xs, w1, gs))
+                 * jax.lax.ragged_dot(xs, w1g, gs))
+            y = jax.lax.ragged_dot(u, w2, gs)
+            y = y * gates.reshape(-1)[perm][:, None].astype(y.dtype)
+            return jnp.zeros_like(xf).at[tok].add(y)
+        return with_vjp(mlp, *a)
+
+    def seconds(all_live, runs=5):
+        jax.block_until_ready(kernels(all_live, *args))
+        times = []
+        for _ in range(runs):
+            t0 = time.perf_counter()
+            jax.block_until_ready(kernels(all_live, *args))
+            times.append(time.perf_counter() - t0)
+        return float(np.median(times))
+
+    gs = np.bincount(np.asarray(idx).ravel(), minlength=held + 1)[:held]
+    n_live = int(np.sum(-(-gs // ops.TM)))
+    grid = ops.grid_tiles(n_tokens * k, held)
+    got, every = kernels(False, *args), kernels(True, *args)
+    want = reference(*args)
+    worst = 0.0
+    for name, a, b, c in zip(("y", "dx", "dgates", "dw1", "dw1g", "dw2"),
+                             got, every, want):
+        a, b, c = (np.asarray(v, np.float32) for v in (a, b, c))
+        err = float(np.linalg.norm(a - c) / np.linalg.norm(c))
+        at = f"held: {name} at the live bound ({n_live} of {grid} tiles)"
+        check(bool(np.isfinite(a).all()), f"{at} finite")
+        check(np.array_equal(a, b), f"{at} equal to every tile live")
+        check(err <= HELD_SHARE_RTOL,
+              f"{at} {err:.2e} <= {HELD_SHARE_RTOL:.2e} of ragged_dot")
+        worst = max(worst, err)
+    t_live, t_all = seconds(False), seconds(True)
+    print(f"[held] forward and VJP {t_live * 1e3:.2f} ms at the bound, "
+          f"{t_all * 1e3:.2f} ms with every tile live (wall clock, median "
+          f"of 5)", flush=True)
+    return n_live, grid, worst, t_live, t_all
 
 
 # ---------------------------------------------------------------------------
@@ -390,6 +502,7 @@ def main(argv=None) -> int:
         print(f"[four-chip] {_memory_line(dev)}", flush=True)
     else:
         xl_rel_phase(seed=args.seed)
+        held_share_phase(seed=args.seed, **HELD_SHARE)
         from repro.configs import get_config
         rung = sort_rung(get_config(TRAIN_ARCH), jnp.bfloat16)
         print(f"[train] {TRAIN_ARCH} sort rung {rung}", flush=True)

@@ -28,14 +28,26 @@ What is proven, per (pipeline family x depth x grid length x pass count):
                        and no DMA is left in flight at the end of a pass — the
                        dW kernels re-enter the stream once per outer pass, so
                        a leaked DMA would collide with the next warmup
+  live bound           for the ``bounded`` families, at every live bound
+                       ``n_live`` from 0 to the grid: steps at or past it run
+                       no part of the schedule, no tile at or past it is ever
+                       issued (it has no rows), and the live tiles keep every
+                       invariant above
 
 Depths swept: ``autotune.SUPPORTED_DEPTHS`` (2/3/4) — the union of what any
 family's candidate enumerator can emit — for every entry in
 ``cvmm.STREAMED_PIPELINES``.
+
+``check_live_blocks`` proves the other half of the live bound: the blocks
+the Mosaic pipeline moves for the kernels' clamped index maps
+(``cvmm.live_step`` / ``cvmm.live_tile``). On every (row, N) grid and every
+bound, live steps keep their own blocks and every slack step repeats the
+last live step's, so the pipeline fetches and writes back nothing after
+the bound, and an output block is never left between two visits.
 """
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 from ..kernels import autotune, cvmm
 from .report import Finding
@@ -47,13 +59,16 @@ MAX_TILES = 9
 REENTRANT_PASSES = (1, 3)
 
 
-def replay_stream(m_tiles: int, n_buffers: int,
-                  n_passes: int = 1) -> List[Tuple[str, int, int]]:
+def replay_stream(m_tiles: int, n_buffers: int, n_passes: int = 1,
+                  n_live: Optional[int] = None) -> List[Tuple[str, int, int]]:
     """Replay ``cvmm.stream_schedule_step`` over a concrete grid.
 
     Returns the flat event list [(kind, tile, slot), ...] with kind one of
     "issue" / "wait" / "compute" / "pass_end" (tile = pass index, slot = -1
-    for pass_end markers), exactly in the order the kernel executes them."""
+    for pass_end markers), exactly in the order the kernel executes them.
+    With ``n_live`` the replay follows the bounded kernels: steps at or past
+    the bound run nothing, and the schedule's tile count is the bound."""
+    live = m_tiles if n_live is None else n_live
     events: List[Tuple[str, int, int]] = []
 
     def issue(t):
@@ -68,7 +83,9 @@ def replay_stream(m_tiles: int, n_buffers: int,
 
     for p in range(n_passes):
         for i in range(m_tiles):
-            slot = cvmm.stream_schedule_step(i, m_tiles, n_buffers,
+            if i >= live:
+                continue
+            slot = cvmm.stream_schedule_step(i, live, n_buffers,
                                              issue=issue, wait=wait, when=when)
             events.append(("compute", i, int(slot)))
         events.append(("pass_end", p, -1))
@@ -76,9 +93,13 @@ def replay_stream(m_tiles: int, n_buffers: int,
 
 
 def check_stream(m_tiles: int, n_buffers: int, n_passes: int = 1,
-                 family: str = "stream") -> Tuple[List[Finding], int]:
-    """Verify one replayed schedule against the hazard invariants."""
+                 family: str = "stream",
+                 n_live: Optional[int] = None) -> Tuple[List[Finding], int]:
+    """Verify one replayed schedule against the hazard invariants. With
+    ``n_live`` the tiles at or past the bound must never be issued."""
+    live = m_tiles if n_live is None else n_live
     loc = (f"{family} depth={n_buffers} m_tiles={m_tiles}"
+           + (f" n_live={n_live}" if n_live is not None else "")
            + (f" passes={n_passes}" if n_passes > 1 else ""))
 
     def bad(check: str, detail: str) -> Finding:
@@ -91,7 +112,7 @@ def check_stream(m_tiles: int, n_buffers: int, n_passes: int = 1,
     issued = {}             # tile -> issue count, this pass
     waited = {}             # tile -> wait count, this pass
 
-    for kind, t, slot in replay_stream(m_tiles, n_buffers, n_passes):
+    for kind, t, slot in replay_stream(m_tiles, n_buffers, n_passes, n_live):
         if kind == "issue":
             checks += 3
             if not (0 <= t < m_tiles):
@@ -99,6 +120,11 @@ def check_stream(m_tiles: int, n_buffers: int, n_passes: int = 1,
                     "issue-out-of-range",
                     f"issued tile {t}, but the grid has {m_tiles} tiles — "
                     f"its chunk table does not exist"))
+            elif t >= live:
+                findings.append(bad(
+                    "issue-past-live",
+                    f"issued tile {t}, at or past the live bound {live}: "
+                    f"no step waits for it"))
             if slot in in_flight:
                 findings.append(bad(
                     "slot-overwrite",
@@ -140,7 +166,7 @@ def check_stream(m_tiles: int, n_buffers: int, n_passes: int = 1,
                     f"pass ended with DMA(s) still in flight: "
                     f"{sorted(in_flight.items())} — the next warmup would "
                     f"overwrite them"))
-            missing = [i for i in range(m_tiles)
+            missing = [i for i in range(live)
                        if issued.get(i, 0) != 1 or waited.get(i, 0) != 1]
             if missing:
                 findings.append(bad(
@@ -151,16 +177,59 @@ def check_stream(m_tiles: int, n_buffers: int, n_passes: int = 1,
     return findings, checks
 
 
+def check_live_blocks(m_tiles: int, n_j: int,
+                      n_live: int) -> Tuple[List[Finding], int]:
+    """Replay the block indices of the clamped index maps over one (m_tiles,
+    n_j) grid, row tile outermost (``cvmm.live_step``; the dW kernels' (b, m)
+    grids are its n_j = 1 case per pass, through ``cvmm.live_tile``)."""
+    loc = f"live blocks m_tiles={m_tiles} n_j={n_j} n_live={n_live}"
+    findings: List[Finding] = []
+    checks = 0
+    seq = [tuple(int(v) for v in cvmm.live_step(i, j, n_live, n_j))
+           for i in range(m_tiles) for j in range(n_j)]
+    n_steps = min(n_live, m_tiles) * n_j
+    checks += 3
+    live_want = [(i, j) for i in range(n_live) for j in range(n_j)]
+    if seq[:n_steps] != live_want:
+        findings.append(Finding(
+            "pipeline", "live-block-moved", loc,
+            "a live step does not use its own blocks"))
+    tail = set(seq[max(n_steps - 1, 0):])
+    if len(tail) > 1:
+        findings.append(Finding(
+            "pipeline", "slack-block-moves", loc,
+            f"slack steps change block indices ({sorted(tail)}): the "
+            f"pipeline would fetch or write back blocks past the bound"))
+    left = [b for k, b in enumerate(seq) if b in seq[:k] and seq[k - 1] != b]
+    if left:
+        findings.append(Finding(
+            "pipeline", "block-revisited", loc,
+            f"block {left[0]} is left and visited again: its first write "
+            f"back would be overwritten"))
+    return findings, checks
+
+
 def check_pipeline() -> Tuple[List[Finding], int]:
-    """Sweep every streamed-pipeline family at every supported depth."""
+    """Sweep every streamed-pipeline family at every supported depth (and,
+    for the bounded ones, every live bound), then the clamped block indices
+    of every grid."""
     findings: List[Finding] = []
     checks = 0
     for family, info in sorted(cvmm.STREAMED_PIPELINES.items()):
         passes = REENTRANT_PASSES if info["reentrant"] else (1,)
         for depth in autotune.SUPPORTED_DEPTHS:
             for m_tiles in range(1, MAX_TILES + 1):
-                for n_passes in passes:
-                    f, c = check_stream(m_tiles, depth, n_passes, family)
-                    findings += f
-                    checks += c
+                bounds = range(m_tiles + 1) if info["bounded"] else (None,)
+                for n_live in bounds:
+                    for n_passes in passes:
+                        f, c = check_stream(m_tiles, depth, n_passes, family,
+                                            n_live)
+                        findings += f
+                        checks += c
+    for m_tiles in range(1, MAX_TILES + 1):
+        for n_j in (1, 2, 3):
+            for n_live in range(m_tiles + 1):
+                f, c = check_live_blocks(m_tiles, n_j, n_live)
+                findings += f
+                checks += c
     return findings, checks

@@ -25,9 +25,10 @@ contract.
                      exactly one chunk; sentinel slack slots by none
 
 ``verify_plan`` adds the per-plan-type structural invariants (permutations,
-tile-expert consistency, sorted-unique prefix, ``sel_pos`` indirection).
-``check_plans`` sweeps the three builders plus ``dispatch.ep_local_plan``
-over adversarial routings (skewed, colliding, empty-expert, sub-tile).
+tile-expert consistency, the live bound, sorted-unique prefix, ``sel_pos``
+indirection). ``check_plans`` sweeps the three builders plus
+``dispatch.ep_local_plan`` over adversarial routings (skewed, colliding,
+empty-expert, sub-tile, a held share of the experts).
 """
 from __future__ import annotations
 
@@ -141,26 +142,44 @@ def replay_chunk_table(plan, n_rows: int, x: Optional[np.ndarray] = None,
 
 def _verify_cvmm_plan(plan: ops.CvmmPlan, n_rows: int,
                       location: str) -> Tuple[List[Finding], int]:
+    """``drop_unheld`` plans pass too: their rows routed elsewhere sort last,
+    count in no group and keep the slot ``M_pad`` (none)."""
     findings: List[Finding] = []
     perm = np.asarray(plan.perm)
     gs = np.asarray(plan.group_sizes)
     new_pos = np.asarray(plan.new_pos)
     te = np.asarray(plan.tile_expert)
     rs = np.asarray(plan.row_src)
+    n_live = np.asarray(plan.n_live)
     gates = np.asarray(plan.gate_tiles).reshape(-1)
-    m = perm.shape[0]
+    m_all = perm.shape[0]
+    m = int(gs.sum())                        # rows that get a slot
     e = gs.shape[0]
-    if not np.array_equal(np.sort(perm), np.arange(m)):
+    if not np.array_equal(np.sort(perm), np.arange(m_all)):
         findings.append(_bad("perm", location,
                              "perm is not a permutation of the sorted rows"))
-    if int(gs.sum()) != m or np.any(gs < 0):
+    if m > m_all or np.any(gs < 0):
         findings.append(_bad("group-sizes", location,
-                             f"group_sizes sums to {int(gs.sum())}, expected "
-                             f"{m} non-negative rows"))
+                             f"group_sizes sums to {m}, expected at most "
+                             f"{m_all} non-negative rows"))
+        m = m_all
+    if np.any(new_pos[m:] != rs.shape[0]):
+        findings.append(_bad("dropped-slot", location,
+                             "rows in no group must keep the slot M_pad"))
+    new_pos = new_pos[:m]
     if np.any(np.diff(te) < 0) or np.any(te < 0) or np.any(te >= e):
         findings.append(_bad("tile-expert", location,
                              "tile_expert must be non-decreasing within "
                              f"[0, {e}), got {te.tolist()}"))
+    if n_live.shape != (1,) or not 0 <= int(n_live[0]) <= te.shape[0]:
+        findings.append(_bad("live-bound", location,
+                             f"n_live must be one count within [0, "
+                             f"{te.shape[0]}], got {n_live.tolist()}"))
+    elif np.any(rs[int(n_live[0]) * TM:] < n_rows):
+        findings.append(_bad(
+            "live-bound", location,
+            f"a real slot sits at or past the live bound {int(n_live[0])}: "
+            f"the kernels would skip its tile"))
     if np.unique(new_pos).shape[0] != m or np.any(new_pos < 0) \
             or np.any(new_pos >= rs.shape[0]):
         findings.append(_bad("new-pos", location,
@@ -188,7 +207,7 @@ def _verify_cvmm_plan(plan: ops.CvmmPlan, n_rows: int,
         findings.append(_bad("row-src-count", location,
                              f"{int((rs < n_rows).sum())} real slots for {m} "
                              f"sorted rows"))
-    return findings, 6
+    return findings, 8
 
 
 def _verify_gather_plan(plan: ops.GatherPlan, n_rows: int,
@@ -282,6 +301,7 @@ _MOE_CASES = (
     ("moe-empty-experts", 57, 5, 2, "subset"),  # some experts get no rows
     ("moe-subtile", 8, 4, 2, "random"),         # n*k < TM
     ("moe-k1", 130, 2, 1, "random"),
+    ("moe-held-share", 200, 2, 3, "held"),      # 2 of 8 experts held here
 )
 _GATHER_CASES = (
     ("gather-random", 40, 300, 4),
@@ -312,15 +332,21 @@ def check_plans() -> Tuple[List[Finding], int]:
             idx = np.zeros((n, k), np.int32)
         elif style == "subset":
             idx = rng.randint(0, max(e - 2, 1), size=(n, k)).astype(np.int32)
+        elif style == "held":
+            # ids 0..e-1 held here, e for the 6 experts held elsewhere
+            idx = np.minimum(rng.randint(0, 4 * e, size=(n, k)),
+                             e).astype(np.int32)
         else:
             idx = rng.randint(0, e, size=(n, k)).astype(np.int32)
         gates = rng.rand(n, k).astype(np.float32)
-        plan = ops.make_moe_plan(jnp.asarray(idx), jnp.asarray(gates), n, e)
+        plan = ops.make_moe_plan(jnp.asarray(idx), jnp.asarray(gates), n, e,
+                                 drop_unheld=style == "held")
         findings += verify_plan(plan, n, name)
         checks += 10
         # routing cross-check: slot contents == the sorted selection
-        perm = np.asarray(plan.perm)
-        new_pos = np.asarray(plan.new_pos)
+        held = int(np.asarray(plan.group_sizes).sum())
+        perm = np.asarray(plan.perm)[:held]
+        new_pos = np.asarray(plan.new_pos)[:held]
         tok = np.repeat(np.arange(n, dtype=np.int32), k)
         if not np.array_equal(np.asarray(plan.row_src)[new_pos], tok[perm]):
             findings.append(_bad(
@@ -332,7 +358,14 @@ def check_plans() -> Tuple[List[Finding], int]:
             findings.append(_bad(
                 "gate-mismatch", name,
                 "gate_tiles disagree with the routed gate values"))
-        checks += 2
+        # the live bound is tight: every tile below it holds a row
+        gs = np.bincount(idx.reshape(-1), minlength=e + 1)[:e]
+        if int(np.asarray(plan.n_live)[0]) != int(np.sum(-(-gs // TM))):
+            findings.append(_bad(
+                "live-bound-tight", name,
+                f"n_live {np.asarray(plan.n_live).tolist()} != "
+                f"sum(ceil(group_size / {TM})) of the routing"))
+        checks += 3
 
     for name, n, rows, s in _GATHER_CASES:
         idx = rng.randint(0, rows, size=(n, s)).astype(np.int32)

@@ -26,6 +26,13 @@ operands/outputs of the streamed kernels count 2x (revolving buffers); the
 plain blocked GEMM counts single-buffered, its pipelining headroom is what
 ``KERNEL_VMEM_FRACTION`` leaves free.
 
+``prefetch_inventory`` itemizes the other memory a launch holds, its
+scalar-prefetch operands in SMEM: the plan's tables and, on every launch of
+the train path, the live bound ``n_live`` (kernels/cvmm.py, "Live bound").
+The bound adds no VMEM buffer: a slack step reuses the last live step's
+blocks. The prefetch check traces each kernel at a small grid and proves
+its scalar operands are the itemized ones ("prefetch-drift").
+
 The threading check then resolves real ``ops.fused_mlp_tiles`` /
 ``ops.planned_call_tiles`` / ``ops.plan_sort_kernels`` decisions over a shape
 grid and proves every (width, depth) pair a launch actually binds is itself a
@@ -97,6 +104,84 @@ def launch_inventory(family: str, dims: Dict[str, int],
         return [("gather scratch (nb,TM,K)", nb * TM * k * b),
                 ("out block (TM,K) x2", 2 * TM * k * b)]
     raise ValueError(f"unknown kernel family {family!r}")
+
+
+# Scalar-prefetch operands of each family's launches, in call order.
+_RUNS = ("row_src", "run_start", "run_off")
+_PREFETCH = {
+    # cvmm_pallas, cvmm_fused_w2_pallas and the decode GEMMs (the same
+    # kernel): the expert of each row tile, the live bound
+    "pick_tn": ("tile_expert", "n_live"),
+    "decode_gemm": ("tile_expert", "n_live"),
+    "fused_w1": _RUNS + ("tile_expert", "n_live"),
+    "streamed_dw": _RUNS + ("tile_expert", "n_live"),
+    "gather": _RUNS,                         # PKM / top-k: no live bound
+    "gather_dedup": _RUNS,
+}
+
+
+def prefetch_inventory(family: str, m_tiles: int) -> List[Tuple[str, int]]:
+    """Itemized scalar-prefetch (SMEM) operands of one launch over
+    ``m_tiles`` row tiles: [(what, int32 elements)]."""
+    from ..kernels import cvmm
+    size = {"row_src": m_tiles * TM, "run_start": m_tiles * TM,
+            "run_off": m_tiles * (len(cvmm._RUN_SIZES) + 1),
+            "tile_expert": m_tiles, "n_live": 1}
+    return [(name, size[name]) for name in _PREFETCH[family]]
+
+
+def _traced_prefetch(family: str, m_tiles: int) -> List[int]:
+    """Element counts of the scalar-prefetch operands of the family's
+    pallas_calls as kernels/cvmm.py builds them (traced, not run)."""
+    import jax
+    import jax.numpy as jnp
+    from ..kernels import cvmm
+
+    m_pad, n = m_tiles * TM, 40
+    x = jnp.zeros((n, LANE), jnp.float32)
+    rows = jnp.zeros((m_pad, LANE), jnp.float32)
+    ints = jnp.zeros((m_pad,), jnp.int32)
+    run_off = jnp.zeros((m_tiles * (len(cvmm._RUN_SIZES) + 1),), jnp.int32)
+    te = jnp.zeros((m_tiles,), jnp.int32)
+    w = jnp.zeros((2, LANE, LANE), jnp.float32)
+    calls = {
+        "pick_tn": [lambda: cvmm.cvmm_pallas(rows, te, w, interpret=True),
+                    lambda: cvmm.cvmm_fused_w2_pallas(
+                        rows, te, w, jnp.zeros((m_tiles, TM)),
+                        interpret=True)],
+        "fused_w1": [lambda: cvmm.cvmm_fused_w1_pallas(
+            x, ints, ints, run_off, te, w, None, act_name="relu",
+            interpret=True)],
+        "streamed_dw": [lambda: cvmm.cvmm_dw_streamed_pallas(
+            x, rows, ints, ints, run_off, te, 2, stream_x=True,
+            interpret=True)],
+        "gather": [lambda: cvmm.cvmm_gather_rows_pallas(
+            x, ints, ints, run_off, interpret=True)],
+    }[family]
+    found = []
+    for call in calls:
+        for eqn in jax.make_jaxpr(call)().eqns:
+            if eqn.primitive.name == "pallas_call":
+                k = eqn.params["grid_mapping"].num_index_operands
+                found.append([int(v.aval.size) for v in eqn.invars[:k]])
+    return found
+
+
+def _check_prefetch():
+    findings: List[Finding] = []
+    checks = 0
+    m_tiles = 3
+    for family in ("pick_tn", "fused_w1", "streamed_dw", "gather"):
+        want = [n for _, n in prefetch_inventory(family, m_tiles)]
+        for got in _traced_prefetch(family, m_tiles):
+            checks += 1
+            if got != want:
+                findings.append(_bad(
+                    "prefetch-drift", f"{family} m_tiles={m_tiles}",
+                    f"the launch prefetches scalar operands of {got} "
+                    f"elements, the inventory itemizes {want} "
+                    f"({list(_PREFETCH[family])})"))
+    return findings, checks
 
 
 def launch_bytes(family: str, dims: Dict[str, int],
@@ -332,4 +417,6 @@ def check_vmem() -> Tuple[List[Finding], int]:
         findings += f
         checks += c
     f, c = _check_threading()
+    findings, checks = findings + f, checks + c
+    f, c = _check_prefetch()
     return findings + f, checks + c

@@ -169,6 +169,11 @@ def apply_moe(params: Dict, x: jax.Array, cfg: FFNConfig, *,
                                              dtype=jnp.float32), axis=(0, 1))
         aux["moe_rows_held"] = jnp.sum(
             (routed.idx < cfg.n_experts).astype(jnp.float32))
+        # the sort plan's live row tiles and all its tiles (kernels/ops.py)
+        from ..kernels import ops as kops
+        gs = jnp.bincount(routed.idx.reshape(-1), length=e)
+        aux["moe_tiles_live"] = kops.live_tiles(gs)[0].astype(jnp.float32)
+        aux["moe_tiles"] = jnp.float32(kops.grid_tiles(routed.idx.size, e))
     if collect_stats:
         aux["usage"] = usage_stats(info, cfg.n_router)
     return y.reshape(*lead, d), aux

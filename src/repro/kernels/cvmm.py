@@ -18,6 +18,8 @@ ops.py computes this layout ONCE per MoE call into a ``CvmmPlan``:
                               table (chunks are grouped largest-class first)
   ``tile_expert`` (M_pad/TM,) row-tile index -> expert id (non-decreasing)
   ``gate_tiles``  (M_pad/TM, TM) float32 gate per padded slot, 0 on slack
+  ``n_live``      (1,) int32 live bound: the row tiles that hold rows,
+                  sum(ceil(group_size / TM)); every tile at or past it is slack
 
 ``tile_expert`` is scalar-prefetched; BlockSpec index_maps use it to stream the
 right expert's weight block HBM->VMEM. This replaces the CUDA kernel's
@@ -133,6 +135,22 @@ plus the plan-invariant, VMEM-budget, and sharding-table passes; CI gates on
 it. When changing the schedule, the chunk-table layout, or a working-set
 formula, run the checker first — it fails faster than a miscompiled kernel.
 
+Live bound
+----------
+``M_pad`` is a static bound (N*K rounded up, plus a tile per expert), so the
+grid has tiles that hold no row: the padding of the plan, and, where a layer
+holds a share of the experts (``make_moe_plan(drop_unheld=True)``), the
+slots of every row routed elsewhere. Every kernel on the train path
+scalar-prefetches ``n_live`` as its last scalar operand. A grid step whose
+row tile is at or past it skips its body (``pl.when``), and its index maps
+return the blocks of the last live step (``live_step``), so the pipeline
+issues no DMA and no writeback for it; the streamed gather stops at the
+bound too. Rows past the bound are left unwritten: every consumer in ops.py
+drops them (sentinel ``row_src`` scatters, ``new_pos`` gathers) or never
+reads them (the clamped kernels), and experts whose dW block no live tile
+visits are zeroed by ``ops._mask_empty``. The grid itself stays static.
+``n_live=None`` means every tile is live (decode, tests of the bare kernels).
+
 Names
 -----
 Every ``pallas_call`` here passes ``name=`` (``cvmm_fwd``, ``cvmm_dw``,
@@ -161,6 +179,30 @@ from .autotune import LANE, TM
 VMEM_BUDGET = autotune.default_vmem_budget()
 N_BUFFERS = 2       # default gather scratch slots (double buffering); the
                     # tuner may thread a deeper pipeline into any streamed call
+
+
+def live_tile(t, n_live):
+    """The row tile whose blocks grid step ``t`` uses: ``t`` itself below the
+    live bound, the last live tile at or past it (tile 0 when none is live).
+    Shared by the kernels' index maps (traced) and the static checker
+    (``repro.analysis.pipeline``, concrete)."""
+    return jnp.minimum(t, jnp.maximum(n_live - 1, 0))
+
+
+def live_step(i, j, n_live, n_j: int):
+    """Block indices (row tile, N tile) of grid step (i, j) on an (m, n_j)
+    grid, row tile outermost: slack steps all take the last live step's
+    (n_live - 1, n_j - 1), so consecutive slack steps never change a block
+    index and the pipeline moves nothing for them."""
+    return live_tile(i, n_live), jnp.where(i < n_live, j, n_j - 1)
+
+
+def _all_live(m_pad: int, n_live):
+    """``n_live`` as the kernels prefetch it; None -> every tile live."""
+    if n_live is None:
+        return jnp.full((1,), m_pad // TM, jnp.int32)
+    return n_live.astype(jnp.int32).reshape(1)
+
 
 # Activations that are elementwise (tile-local) and therefore legal to apply
 # inside a kernel epilogue on an (TM, TN) tile.
@@ -241,19 +283,23 @@ def legacy_whole_x_rows(k_pad: int, bytes_per_el: int, n_weights: int,
 # Forward kernel (unfused building block)
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(tile_expert_ref, x_ref, w_ref, o_ref):
+def _fwd_kernel(tile_expert_ref, n_live_ref, x_ref, w_ref, o_ref):
     # x_ref: (TM, K), w_ref: (1, K, TN), o_ref: (TM, TN)
-    acc = jnp.dot(x_ref[...], w_ref[0],
-                  preferred_element_type=jnp.float32)
-    o_ref[...] = acc.astype(o_ref.dtype)
+    @pl.when(pl.program_id(0) < n_live_ref[0])
+    def _():
+        acc = jnp.dot(x_ref[...], w_ref[0],
+                      preferred_element_type=jnp.float32)
+        o_ref[...] = acc.astype(o_ref.dtype)
 
 
 def cvmm_pallas(x_pad: jax.Array, tile_expert: jax.Array, w: jax.Array,
                 *, interpret: bool = False,
-                tn: int | None = None) -> jax.Array:
+                tn: int | None = None,
+                n_live: jax.Array | None = None) -> jax.Array:
     """x_pad (M_pad, K_pad) sorted+tile-aligned rows; tile_expert (M_pad//TM,) int32;
-    w (E, K_pad, N_pad). Returns (M_pad, N_pad). ``tn`` threads a pre-resolved
-    tile choice (ops.py / the tuner); omitted -> heuristic query."""
+    w (E, K_pad, N_pad). Returns (M_pad, N_pad); rows of tiles at or past
+    ``n_live`` are left unwritten. ``tn`` threads a pre-resolved tile choice
+    (ops.py / the tuner); omitted -> heuristic query."""
     m_pad, k_pad = x_pad.shape
     e, k_w, n_pad = w.shape
     assert k_w == k_pad and m_pad % TM == 0 and k_pad % LANE == 0 and n_pad % LANE == 0
@@ -261,40 +307,48 @@ def cvmm_pallas(x_pad: jax.Array, tile_expert: jax.Array, w: jax.Array,
         tn = _pick_tn(k_pad, n_pad, x_pad.dtype.itemsize)
     tn = _require_tn(tn, "cvmm_pallas", k_pad)
     grid = (m_pad // TM, n_pad // tn)
+    n_j = grid[1]
+
+    def x_map(i, j, te, nl):
+        return live_tile(i, nl[0]), 0
+
+    def w_map(i, j, te, nl):
+        ic, jc = live_step(i, j, nl[0], n_j)
+        return te[ic], 0, jc
+
+    def o_map(i, j, te, nl):
+        return live_step(i, j, nl[0], n_j)
 
     return pl.pallas_call(
         _fwd_kernel,
         name="cvmm_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((TM, k_pad), lambda i, j, te: (i, 0)),
-                pl.BlockSpec((1, k_pad, tn), lambda i, j, te: (te[i], 0, j)),
+                pl.BlockSpec((TM, k_pad), x_map),
+                pl.BlockSpec((1, k_pad, tn), w_map),
             ],
-            out_specs=pl.BlockSpec((TM, tn), lambda i, j, te: (i, j)),
+            out_specs=pl.BlockSpec((TM, tn), o_map),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), x_pad.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tile_expert, x_pad, w)
+    )(tile_expert, _all_live(m_pad, n_live), x_pad, w)
 
 
 # ---------------------------------------------------------------------------
 # dW kernel (grouped outer-product accumulation)
 # ---------------------------------------------------------------------------
 
-def _dw_kernel(tile_expert_ref, x_ref, g_ref, o_ref):
-    # grid (k_tiles, n_tiles, m_tiles); m innermost.
-    m = pl.program_id(2)
-    e_now = tile_expert_ref[m]
-    e_prev = tile_expert_ref[jnp.maximum(m - 1, 0)]
-    first = jnp.logical_or(m == 0, e_now != e_prev)
-    acc = jax.lax.dot_general(
-        x_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                  # (TK, TN)
+def _dw_first(te_ref, m):
+    e_now = te_ref[m]
+    e_prev = te_ref[jnp.maximum(m - 1, 0)]
+    return jnp.logical_or(m == 0, e_now != e_prev)
 
+
+def _dw_accumulate(o_ref, acc, first):
     @pl.when(first)
     def _init():
         o_ref[0] = acc
@@ -304,10 +358,25 @@ def _dw_kernel(tile_expert_ref, x_ref, g_ref, o_ref):
         o_ref[0] += acc
 
 
+def _dw_kernel(tile_expert_ref, n_live_ref, x_ref, g_ref, o_ref):
+    # grid (k_tiles, n_tiles, m_tiles); m innermost.
+    m = pl.program_id(2)
+
+    @pl.when(m < n_live_ref[0])
+    def _():
+        acc = jax.lax.dot_general(
+            x_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (TK, TN)
+        _dw_accumulate(o_ref, acc, _dw_first(tile_expert_ref, m))
+
+
 def cvmm_dw_pallas(x_pad: jax.Array, tile_expert: jax.Array, g_pad: jax.Array,
                    n_experts: int, *, interpret: bool = False,
-                   tk: int | None = None, tn: int | None = None) -> jax.Array:
-    """dW (E, K_pad, N_pad) float32 from tile-aligned x (M_pad, K_pad), g (M_pad, N_pad)."""
+                   tk: int | None = None, tn: int | None = None,
+                   n_live: jax.Array | None = None) -> jax.Array:
+    """dW (E, K_pad, N_pad) float32 from tile-aligned x (M_pad, K_pad), g (M_pad, N_pad).
+    Tiles at or past ``n_live`` add nothing; an expert no live tile visits
+    keeps an unwritten block (callers mask it, ``ops._mask_empty``)."""
     m_pad, k_pad = x_pad.shape
     _, n_pad = g_pad.shape
     assert m_pad % TM == 0 and k_pad % LANE == 0 and n_pad % LANE == 0
@@ -323,19 +392,22 @@ def cvmm_dw_pallas(x_pad: jax.Array, tile_expert: jax.Array, g_pad: jax.Array,
         _dw_kernel,
         name="cvmm_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((TM, tk), lambda k, n, m, te: (m, k)),
-                pl.BlockSpec((TM, tn), lambda k, n, m, te: (m, n)),
+                pl.BlockSpec((TM, tk), lambda k, n, m, te, nl:
+                             (live_tile(m, nl[0]), k)),
+                pl.BlockSpec((TM, tn), lambda k, n, m, te, nl:
+                             (live_tile(m, nl[0]), n)),
             ],
-            out_specs=pl.BlockSpec((1, tk, tn), lambda k, n, m, te: (te[m], k, n)),
+            out_specs=pl.BlockSpec((1, tk, tn), lambda k, n, m, te, nl:
+                                   (te[live_tile(m, nl[0])], k, n)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_experts, k_pad, n_pad), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tile_expert, x_pad, g_pad)
+    )(tile_expert, _all_live(m_pad, n_live), x_pad, g_pad)
 
 
 # ---------------------------------------------------------------------------
@@ -353,11 +425,16 @@ _RUN_SIZES = tuple(1 << b for b in range(TM.bit_length() - 1, -1, -1))
 # w1 kernel steps the stream only on the first N-tile of each row tile, so it
 # behaves as the single-pass gather. repro.analysis.pipeline replays every
 # entry here at every supported depth.
+# ``bounded`` families stop their stream at the live bound (``n_live``): steps
+# at or past it run no part of the schedule, and the schedule's tile count is
+# the bound itself.
 STREAMED_PIPELINES = {
-    "fused_w1": dict(reentrant=False),     # grid (m, n); stream stepped at j==0
-    "gather": dict(reentrant=False),       # grid (m,)
-    "dw_streamed": dict(reentrant=True),   # grid (b, m), m innermost; the
-                                           # stream restarts on every b pass
+    "fused_w1": dict(reentrant=False,      # grid (m, n); stream stepped at j==0
+                     bounded=True),
+    "gather": dict(reentrant=False,        # grid (m,)
+                   bounded=False),
+    "dw_streamed": dict(reentrant=True,    # grid (b, m), m innermost; the
+                        bounded=True),     # stream restarts on every b pass
 }
 
 
@@ -496,7 +573,8 @@ def _gather_wait(t, row_src_ref, run_start_ref, run_off_ref, x_hbm, xs_ref,
 
 
 def _stream_tile(i, row_src_ref, run_start_ref, run_off_ref, x_hbm, xs_ref,
-                 sem_ref, *, axis: int = 0, n_buffers: int = N_BUFFERS):
+                 sem_ref, *, axis: int = 0, n_buffers: int = N_BUFFERS,
+                 n_live=None):
     """Pipelined gather step for row tile ``i`` (grid dim ``axis``, sequential
     and innermost), ``n_buffers`` scratch slots deep.
 
@@ -511,10 +589,14 @@ def _stream_tile(i, row_src_ref, run_start_ref, run_off_ref, x_hbm, xs_ref,
     tiles and prefetches past the last tile are suppressed, so no DMA is left
     in flight across pass boundaries.
 
+    With ``n_live`` (a traced count, at most the grid's) the stream ends at
+    the live bound: the caller runs this only for ``i < n_live``, and no
+    tile past the bound is prefetched.
+
     The actual issue/wait ordering lives in ``stream_schedule_step`` (shared
     with the static hazard checker); this wrapper only binds the DMA
     callbacks."""
-    m_tiles = pl.num_programs(axis)
+    m_tiles = pl.num_programs(axis) if n_live is None else n_live
 
     def issue(t):
         _gather_issue(t, row_src_ref, run_start_ref, run_off_ref, x_hbm,
@@ -528,43 +610,50 @@ def _stream_tile(i, row_src_ref, run_start_ref, run_off_ref, x_hbm, xs_ref,
                                 when=lambda cond, fn: pl.when(cond)(fn))
 
 
-def _fused_w1_body(row_src_ref, run_start_ref, run_off_ref, x_hbm, w1_ref,
-                   w1g_ref, o_u_ref, o_h_ref, o_hg_ref, xs_ref, sem_ref,
-                   *, act_name: str, n_buffers: int = N_BUFFERS):
+def _fused_w1_body(row_src_ref, run_start_ref, run_off_ref, n_live_ref,
+                   x_hbm, w1_ref, w1g_ref, o_u_ref, o_h_ref, o_hg_ref, xs_ref,
+                   sem_ref, *, act_name: str, n_buffers: int = N_BUFFERS):
     i, j = pl.program_id(0), pl.program_id(1)
+    n_live = n_live_ref[0]
 
-    @pl.when(j == 0)
+    @pl.when(i < n_live)
     def _():
-        _stream_tile(i, row_src_ref, run_start_ref, run_off_ref, x_hbm,
-                     xs_ref, sem_ref, n_buffers=n_buffers)
-    xt = _slot_tile(xs_ref, stream_slot(i, n_buffers))
-    h = jnp.dot(xt, w1_ref[0], preferred_element_type=jnp.float32)
-    u = act_fn(act_name)(h)
-    if w1g_ref is not None:
-        hg = jnp.dot(xt, w1g_ref[0],
-                     preferred_element_type=jnp.float32)
-        u = u * hg
-        if o_hg_ref is not None:
-            o_hg_ref[...] = hg.astype(o_hg_ref.dtype)
-    if o_h_ref is not None:
-        o_h_ref[...] = h.astype(o_h_ref.dtype)
-    o_u_ref[...] = u.astype(o_u_ref.dtype)
+        @pl.when(j == 0)
+        def _():
+            _stream_tile(i, row_src_ref, run_start_ref, run_off_ref, x_hbm,
+                         xs_ref, sem_ref, n_buffers=n_buffers, n_live=n_live)
+        xt = _slot_tile(xs_ref, stream_slot(i, n_buffers))
+        h = jnp.dot(xt, w1_ref[0], preferred_element_type=jnp.float32)
+        u = act_fn(act_name)(h)
+        if w1g_ref is not None:
+            hg = jnp.dot(xt, w1g_ref[0],
+                         preferred_element_type=jnp.float32)
+            u = u * hg
+            if o_hg_ref is not None:
+                o_hg_ref[...] = hg.astype(o_hg_ref.dtype)
+        if o_h_ref is not None:
+            o_h_ref[...] = h.astype(o_h_ref.dtype)
+        o_u_ref[...] = u.astype(o_u_ref.dtype)
 
 
-def _k_w1(rs, rst, rl, te, x, w1, o_u, xs, sem, **kw):
-    _fused_w1_body(rs, rst, rl, x, w1, None, o_u, None, None, xs, sem, **kw)
+def _k_w1(rs, rst, rl, te, nl, x, w1, o_u, xs, sem, **kw):
+    _fused_w1_body(rs, rst, rl, nl, x, w1, None, o_u, None, None, xs, sem,
+                   **kw)
 
 
-def _k_w1_save(rs, rst, rl, te, x, w1, o_u, o_h, xs, sem, **kw):
-    _fused_w1_body(rs, rst, rl, x, w1, None, o_u, o_h, None, xs, sem, **kw)
+def _k_w1_save(rs, rst, rl, te, nl, x, w1, o_u, o_h, xs, sem, **kw):
+    _fused_w1_body(rs, rst, rl, nl, x, w1, None, o_u, o_h, None, xs, sem,
+                   **kw)
 
 
-def _k_w1_glu(rs, rst, rl, te, x, w1, w1g, o_u, xs, sem, **kw):
-    _fused_w1_body(rs, rst, rl, x, w1, w1g, o_u, None, None, xs, sem, **kw)
+def _k_w1_glu(rs, rst, rl, te, nl, x, w1, w1g, o_u, xs, sem, **kw):
+    _fused_w1_body(rs, rst, rl, nl, x, w1, w1g, o_u, None, None, xs, sem,
+                   **kw)
 
 
-def _k_w1_glu_save(rs, rst, rl, te, x, w1, w1g, o_u, o_h, o_hg, xs, sem, **kw):
-    _fused_w1_body(rs, rst, rl, x, w1, w1g, o_u, o_h, o_hg, xs, sem, **kw)
+def _k_w1_glu_save(rs, rst, rl, te, nl, x, w1, w1g, o_u, o_h, o_hg, xs, sem,
+                   **kw):
+    _fused_w1_body(rs, rst, rl, nl, x, w1, w1g, o_u, o_h, o_hg, xs, sem, **kw)
 
 
 def cvmm_fused_w1_pallas(x: jax.Array, row_src: jax.Array,
@@ -574,7 +663,8 @@ def cvmm_fused_w1_pallas(x: jax.Array, row_src: jax.Array,
                          save_preact: bool = False,
                          interpret: bool = False,
                          tn: int | None = None,
-                         n_buffers: int | None = None):
+                         n_buffers: int | None = None,
+                         n_live: jax.Array | None = None):
     """Streamed gather-fused grouped GEMM with activation(/GLU) epilogue.
 
     x (N_rows, K_pad) — the UNSORTED activations, left in HBM (``pl.ANY``)
@@ -592,6 +682,7 @@ def cvmm_fused_w1_pallas(x: jax.Array, row_src: jax.Array,
     ``save_preact=True`` (training: the custom_vjp forward rule) additionally
     writes the pre-activations h (and hg with GLU) in the same grid pass, so
     the backward pass needs no recompute GEMMs; returns (u, h[, hg]).
+    Rows of tiles at or past ``n_live`` are left unwritten.
 
     ``tn`` / ``n_buffers`` (the N-tile width and gather pipeline depth) are
     normally resolved once per plan by ops.py via the tuner and threaded in;
@@ -613,13 +704,21 @@ def cvmm_fused_w1_pallas(x: jax.Array, row_src: jax.Array,
             f"{k_pad}; gate calls with ops.fused_supported")
     n_buffers = N_BUFFERS if n_buffers is None else n_buffers
     grid = (m_pad // TM, g_pad // tn)
+    n_j = grid[1]
 
-    w_spec = pl.BlockSpec((1, k_pad, tn),
-                          lambda i, j, rs, rst, rl, te: (te[i], 0, j))
-    o_spec = pl.BlockSpec((TM, tn), lambda i, j, rs, rst, rl, te: (i, j))
+    def w_map(i, j, rs, rst, rl, te, nl):
+        ic, jc = live_step(i, j, nl[0], n_j)
+        return te[ic], 0, jc
+
+    def o_map(i, j, rs, rst, rl, te, nl):
+        return live_step(i, j, nl[0], n_j)
+
+    w_spec = pl.BlockSpec((1, k_pad, tn), w_map)
+    o_spec = pl.BlockSpec((TM, tn), o_map)
     o_shape = jax.ShapeDtypeStruct((m_pad, g_pad), x.dtype)
     in_specs = [pl.BlockSpec(memory_space=pl.ANY), w_spec]
-    operands = [row_src, run_start, run_off, tile_expert, _row_view(x), w1]
+    operands = [row_src, run_start, run_off, tile_expert,
+                _all_live(m_pad, n_live), _row_view(x), w1]
     if w1g is not None:
         in_specs.append(w_spec)
         operands.append(w1g)
@@ -632,7 +731,7 @@ def cvmm_fused_w1_pallas(x: jax.Array, row_src: jax.Array,
         kernel,
         name="cvmm_fused_w1",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=grid,
             in_specs=in_specs,
             out_specs=[o_spec] * n_out,
@@ -732,57 +831,49 @@ def cvmm_gather_rows_pallas(x: jax.Array, row_src: jax.Array,
 # Streamed dW kernels (backward: no tile-aligned gather ever hits HBM)
 # ---------------------------------------------------------------------------
 
-def _dw_first(te_ref, m):
-    e_now = te_ref[m]
-    e_prev = te_ref[jnp.maximum(m - 1, 0)]
-    return jnp.logical_or(m == 0, e_now != e_prev)
-
-
-def _dw_accumulate(o_ref, acc, first):
-    @pl.when(first)
-    def _init():
-        o_ref[0] = acc
-
-    @pl.when(jnp.logical_not(first))
-    def _acc():
-        o_ref[0] += acc
-
-
-def _dw_stream_x_kernel(rs, rst, rl, te, x_hbm, g_ref, o_ref, xs_ref, sem_ref,
-                        *, n_buffers: int = N_BUFFERS):
+def _dw_stream_x_kernel(rs, rst, rl, te, nl, x_hbm, g_ref, o_ref, xs_ref,
+                        sem_ref, *, n_buffers: int = N_BUFFERS):
     # grid (n_tiles, m_tiles), m innermost; the stream restarts per n pass.
     m = pl.program_id(1)
-    slot = _stream_tile(m, rs, rst, rl, x_hbm, xs_ref, sem_ref, axis=1,
-                        n_buffers=n_buffers)
-    acc = jax.lax.dot_general(_slot_tile(xs_ref, slot), g_ref[...],
-                              (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)  # (K, tb)
-    _dw_accumulate(o_ref, acc, _dw_first(te, m))
+
+    @pl.when(m < nl[0])
+    def _():
+        slot = _stream_tile(m, rs, rst, rl, x_hbm, xs_ref, sem_ref, axis=1,
+                            n_buffers=n_buffers, n_live=nl[0])
+        acc = jax.lax.dot_general(_slot_tile(xs_ref, slot), g_ref[...],
+                                  (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        _dw_accumulate(o_ref, acc, _dw_first(te, m))             # (K, tb)
 
 
-def _dw_stream_g_body(rs, rst, rl, g_hbm, x_ref, gate_ref, o_ref, gs_ref,
-                      sem_ref, te, n_buffers: int = N_BUFFERS):
+def _dw_stream_g_body(rs, rst, rl, te, nl, g_hbm, x_ref, gate_ref, o_ref,
+                      gs_ref, sem_ref, n_buffers: int = N_BUFFERS):
     m = pl.program_id(1)
-    slot = _stream_tile(m, rs, rst, rl, g_hbm, gs_ref, sem_ref, axis=1,
-                        n_buffers=n_buffers)
-    gt = _slot_tile(gs_ref, slot)
-    if gate_ref is not None:
-        gt = (gt.astype(jnp.float32) * _gate_column(gate_ref)).astype(gt.dtype)
-    acc = jax.lax.dot_general(x_ref[...], gt, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)  # (tb, N)
-    _dw_accumulate(o_ref, acc, _dw_first(te, m))
+
+    @pl.when(m < nl[0])
+    def _():
+        slot = _stream_tile(m, rs, rst, rl, g_hbm, gs_ref, sem_ref, axis=1,
+                            n_buffers=n_buffers, n_live=nl[0])
+        gt = _slot_tile(gs_ref, slot)
+        if gate_ref is not None:
+            gt = (gt.astype(jnp.float32)
+                  * _gate_column(gate_ref)).astype(gt.dtype)
+        acc = jax.lax.dot_general(x_ref[...], gt, (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        _dw_accumulate(o_ref, acc, _dw_first(te, m))             # (tb, N)
 
 
-def _dw_stream_g_kernel(rs, rst, rl, te, g_hbm, x_ref, o_ref, gs_ref, sem_ref,
-                        *, n_buffers: int = N_BUFFERS):
-    _dw_stream_g_body(rs, rst, rl, g_hbm, x_ref, None, o_ref, gs_ref, sem_ref,
-                      te, n_buffers)
+def _dw_stream_g_kernel(rs, rst, rl, te, nl, g_hbm, x_ref, o_ref, gs_ref,
+                        sem_ref, *, n_buffers: int = N_BUFFERS):
+    _dw_stream_g_body(rs, rst, rl, te, nl, g_hbm, x_ref, None, o_ref, gs_ref,
+                      sem_ref, n_buffers)
 
 
-def _dw_stream_g_gate_kernel(rs, rst, rl, te, g_hbm, x_ref, gate_ref, o_ref,
-                             gs_ref, sem_ref, *, n_buffers: int = N_BUFFERS):
-    _dw_stream_g_body(rs, rst, rl, g_hbm, x_ref, gate_ref, o_ref, gs_ref,
-                      sem_ref, te, n_buffers)
+def _dw_stream_g_gate_kernel(rs, rst, rl, te, nl, g_hbm, x_ref, gate_ref,
+                             o_ref, gs_ref, sem_ref, *,
+                             n_buffers: int = N_BUFFERS):
+    _dw_stream_g_body(rs, rst, rl, te, nl, g_hbm, x_ref, gate_ref, o_ref,
+                      gs_ref, sem_ref, n_buffers)
 
 
 def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
@@ -792,7 +883,8 @@ def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
                             gate_tiles: jax.Array | None = None,
                             interpret: bool = False,
                             tb: int | None = None,
-                            n_buffers: int | None = None) -> jax.Array:
+                            n_buffers: int | None = None,
+                            n_live: jax.Array | None = None) -> jax.Array:
     """dW (E, K_pad, N_pad) float32 with ONE operand streamed from unsorted HBM.
 
     stream_x=True : ``x`` is the UNSORTED (N_rows, K_pad) activations, left in
@@ -808,7 +900,9 @@ def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
     Grid (blocked_w // tb, m_tiles) with the row-tile loop innermost:
     ``tile_expert`` is non-decreasing, so output-block revisits stay
     consecutive and accumulation is legal; the gather stream restarts on each
-    outer pass (the scratch only ever holds two row tiles)."""
+    outer pass (the scratch only ever holds two row tiles). Row tiles at or
+    past ``n_live`` add nothing; an expert no live tile visits keeps an
+    unwritten block (callers mask it, ``ops._mask_empty``)."""
     assert gate_tiles is None or not stream_x
     m_pad = row_src.shape[0]
     if stream_x:
@@ -833,22 +927,27 @@ def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
     n_buffers = N_BUFFERS if n_buffers is None else n_buffers
     grid = (block_w // tb, m_pad // TM)
     scratch = _stream_scratch(n_buffers, stream_w, sdtype)
-    blk_spec = pl.BlockSpec((TM, tb), lambda b, m, *s: (m, b))
+    prefetch = [row_src, run_start, run_off, tile_expert,
+                _all_live(m_pad, n_live)]
+    blk_spec = pl.BlockSpec((TM, tb), lambda b, m, rs, rst, rl, te, nl:
+                            (live_tile(m, nl[0]), b))
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY), blk_spec]
     if stream_x:
-        in_specs = [pl.BlockSpec(memory_space=pl.ANY), blk_spec]
-        operands = [row_src, run_start, run_off, tile_expert, _row_view(x), g]
+        operands = prefetch + [_row_view(x), g]
         out_spec = pl.BlockSpec(
-            (1, k_pad, tb), lambda b, m, rs, rst, rl, te: (te[m], 0, b))
+            (1, k_pad, tb), lambda b, m, rs, rst, rl, te, nl:
+            (te[live_tile(m, nl[0])], 0, b))
         kernel = _dw_stream_x_kernel
     else:
-        in_specs = [pl.BlockSpec(memory_space=pl.ANY), blk_spec]
-        operands = [row_src, run_start, run_off, tile_expert, _row_view(g), x]
+        operands = prefetch + [_row_view(g), x]
         out_spec = pl.BlockSpec(
-            (1, tb, n_pad), lambda b, m, rs, rst, rl, te: (te[m], b, 0))
+            (1, tb, n_pad), lambda b, m, rs, rst, rl, te, nl:
+            (te[live_tile(m, nl[0])], b, 0))
         if gate_tiles is not None:
             assert gate_tiles.shape == (m_pad // TM, TM)
-            in_specs.append(pl.BlockSpec((1, 1, TM),
-                                         lambda b, m, *s: (m, 0, 0)))
+            in_specs.append(pl.BlockSpec(
+                (1, 1, TM), lambda b, m, rs, rst, rl, te, nl:
+                (live_tile(m, nl[0]), 0, 0)))
             operands.append(_gate_view(gate_tiles))
             kernel = _dw_stream_g_gate_kernel
         else:
@@ -858,7 +957,7 @@ def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
         functools.partial(kernel, n_buffers=n_buffers),
         name="cvmm_dw_streamed",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=grid,
             in_specs=in_specs,
             out_specs=out_spec,
@@ -871,19 +970,25 @@ def cvmm_dw_streamed_pallas(x: jax.Array, g: jax.Array, row_src: jax.Array,
     )(*operands)
 
 
-def _fused_w2_kernel(tile_expert_ref, u_ref, w2_ref, gate_ref, o_ref):
-    acc = jnp.dot(u_ref[...], w2_ref[0], preferred_element_type=jnp.float32)
-    o_ref[...] = (acc * _gate_column(gate_ref)).astype(o_ref.dtype)
+def _fused_w2_kernel(tile_expert_ref, n_live_ref, u_ref, w2_ref, gate_ref,
+                     o_ref):
+    @pl.when(pl.program_id(0) < n_live_ref[0])
+    def _():
+        acc = jnp.dot(u_ref[...], w2_ref[0],
+                      preferred_element_type=jnp.float32)
+        o_ref[...] = (acc * _gate_column(gate_ref)).astype(o_ref.dtype)
 
 
 def cvmm_fused_w2_pallas(u_pad: jax.Array, tile_expert: jax.Array,
                          w2: jax.Array, gate_tiles: jax.Array,
                          *, interpret: bool = False,
-                         tn: int | None = None) -> jax.Array:
+                         tn: int | None = None,
+                         n_live: jax.Array | None = None) -> jax.Array:
     """Grouped GEMM with the per-row gate multiply fused into the epilogue.
 
     u_pad (M_pad, G_pad) tile-aligned; w2 (E, G_pad, N_pad);
-    gate_tiles (M_pad//TM, TM) float32. Returns (M_pad, N_pad)."""
+    gate_tiles (M_pad//TM, TM) float32. Returns (M_pad, N_pad); rows of
+    tiles at or past ``n_live`` are left unwritten."""
     m_pad, g_pad = u_pad.shape
     e, g_w, n_pad = w2.shape
     assert g_w == g_pad and m_pad % TM == 0
@@ -893,22 +998,33 @@ def cvmm_fused_w2_pallas(u_pad: jax.Array, tile_expert: jax.Array,
         tn = _pick_tn(g_pad, n_pad, u_pad.dtype.itemsize)
     tn = _require_tn(tn, "cvmm_fused_w2_pallas", g_pad)
     grid = (m_pad // TM, n_pad // tn)
+    n_j = grid[1]
+
+    def w_map(i, j, te, nl):
+        ic, jc = live_step(i, j, nl[0], n_j)
+        return te[ic], 0, jc
+
+    def o_map(i, j, te, nl):
+        return live_step(i, j, nl[0], n_j)
 
     return pl.pallas_call(
         _fused_w2_kernel,
         name="cvmm_fused_w2",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((TM, g_pad), lambda i, j, te: (i, 0)),
-                pl.BlockSpec((1, g_pad, tn), lambda i, j, te: (te[i], 0, j)),
-                pl.BlockSpec((1, 1, TM), lambda i, j, te: (i, 0, 0)),
+                pl.BlockSpec((TM, g_pad), lambda i, j, te, nl:
+                             (live_tile(i, nl[0]), 0)),
+                pl.BlockSpec((1, g_pad, tn), w_map),
+                pl.BlockSpec((1, 1, TM), lambda i, j, te, nl:
+                             (live_tile(i, nl[0]), 0, 0)),
             ],
-            out_specs=pl.BlockSpec((TM, tn), lambda i, j, te: (i, j)),
+            out_specs=pl.BlockSpec((TM, tn), o_map),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), u_pad.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tile_expert, u_pad, w2, _gate_view(gate_tiles))
+    )(tile_expert, _all_live(m_pad, n_live), u_pad, w2,
+      _gate_view(gate_tiles))

@@ -105,6 +105,8 @@ class CvmmPlan(NamedTuple):
                              #   — lets kernels loop per static class with no
                              #   per-entry size dispatch.
     tile_expert: jax.Array   # (M_pad//TM,) row-tile -> expert id
+    n_live: jax.Array        # (1,) int32 live bound: tiles [0, n_live) hold
+                             #   every row; kernels skip the tiles past it
     gate_tiles: jax.Array    # (M_pad//TM, TM) float32 gate per slot, 0 on slack
 
     @property
@@ -112,11 +114,26 @@ class CvmmPlan(NamedTuple):
         return self.tile_expert.shape[0] * TM
 
 
+def grid_tiles(m: int, e: int) -> int:
+    """Row tiles of the static layout bound for ``m`` rows over ``e``
+    experts: m rounded up to TM, plus one tile of padding per expert."""
+    return round_up(m, TM) // TM + e
+
+
+def live_tiles(group_sizes: jax.Array) -> jax.Array:
+    """(1,) int32: the row tiles that hold rows, sum(ceil(gs / TM)). The
+    layout packs them first, so every tile at or past this count is slack.
+    The plan's ``n_live`` and the step's ``moe_tile_share`` both read it."""
+    gs = group_sizes.astype(jnp.int32)
+    return jnp.sum((gs + TM - 1) // TM, keepdims=True)
+
+
 def _tile_layout(group_sizes: jax.Array, m: int, e: int):
     """Map sorted rows to a layout where each expert's range is TM-aligned.
 
-    Returns (new_pos (m,), tile_expert (m_pad//TM,), m_pad). m_pad is a static
-    upper bound m + e*TM; slack tiles are all-zero and clamped to the last expert.
+    Returns (new_pos (m,), tile_expert (m_pad//TM,), n_live (1,), m_pad).
+    m_pad is a static upper bound m + e*TM; slack tiles are all-zero, sit
+    at or past ``n_live``, and are clamped to the last expert.
     """
     gs = group_sizes.astype(jnp.int32)
     offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(gs)])[:-1]
@@ -125,13 +142,12 @@ def _tile_layout(group_sizes: jax.Array, m: int, e: int):
     rows = jnp.arange(m, dtype=jnp.int32)
     re = refk.row_experts(gs, m).astype(jnp.int32)
     new_pos = offs_p[re] + (rows - offs[re])
-    m_pad = round_up(m, TM) + e * TM
-    n_tiles = m_pad // TM
+    n_tiles = grid_tiles(m, e)
     ends_p = jnp.cumsum(ps)
     tile_expert = jnp.searchsorted(ends_p, jnp.arange(n_tiles, dtype=jnp.int32) * TM,
                                    side="right").astype(jnp.int32)
     tile_expert = jnp.minimum(tile_expert, e - 1)         # clamp slack tiles
-    return new_pos, tile_expert, m_pad
+    return new_pos, tile_expert, live_tiles(gs), n_tiles * TM
 
 
 def _plan_runs(row_src: jax.Array, n_rows: int):
@@ -202,15 +218,16 @@ def make_moe_plan(idx: jax.Array, gates: jax.Array, n_tokens: int,
     ``gates`` (the scatter into ``gate_tiles`` is transparent to autodiff).
     ``drop_unheld``: ids may also be ``n_experts``, a row routed to an expert
     this layer does not hold; such rows sort last, join no group and get no
-    slot (row_src keeps the sentinel there)."""
+    slot (row_src keeps the sentinel there). Either way ``n_live`` counts
+    the tiles of the rows that got slots."""
     k = idx.shape[-1]
     e_flat = idx.reshape(-1).astype(jnp.int32)
     g_flat = gates.reshape(-1)
     tok = jnp.repeat(jnp.arange(n_tokens, dtype=jnp.int32), k)
     perm = jnp.argsort(e_flat, stable=True)
     group_sizes = jnp.bincount(e_flat, length=n_experts).astype(jnp.int32)
-    new_pos, tile_expert, m_pad = _tile_layout(group_sizes, e_flat.shape[0],
-                                               n_experts)
+    new_pos, tile_expert, n_live, m_pad = _tile_layout(
+        group_sizes, e_flat.shape[0], n_experts)
     if drop_unheld:
         new_pos = jnp.where(e_flat[perm] < n_experts, new_pos, m_pad)
     row_src = jnp.full((m_pad,), n_tokens, jnp.int32).at[new_pos].set(tok[perm])
@@ -219,7 +236,7 @@ def make_moe_plan(idx: jax.Array, gates: jax.Array, n_tokens: int,
         g_flat[perm].astype(jnp.float32))
     return CvmmPlan(perm=perm, group_sizes=group_sizes, new_pos=new_pos,
                     row_src=row_src, run_start=run_start, run_len=run_len,
-                    run_off=run_off, tile_expert=tile_expert,
+                    run_off=run_off, tile_expert=tile_expert, n_live=n_live,
                     gate_tiles=gate_pad.reshape(m_pad // TM, TM))
 
 
@@ -282,7 +299,7 @@ def _pad_w(w: jax.Array) -> jax.Array:
 
 def _mask_empty(dw: jax.Array, group_sizes: jax.Array) -> jax.Array:
     # Blocks of experts with zero rows are never visited by the dW kernel
-    # (their padded group has no tiles) and stay uninitialized.
+    # (their padded group has no live tile) and stay uninitialized.
     return jnp.where((group_sizes > 0)[:, None, None], dw, 0.0)
 
 
@@ -727,28 +744,37 @@ def plan_sort_kernels(impl: str, d_model: int, expert_size: int,
 # Unfused pallas path with plan-threaded custom_vjp
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _cvmm_planned(x, new_pos, tile_expert, group_sizes, w, interpret,
+def _slot_rows(a: jax.Array, new_pos: jax.Array, n: int) -> jax.Array:
+    """a[new_pos, :n]; a slot past M_pad (a row held elsewhere) reads 0, and
+    no slot reads a row past the live bound, which the kernels leave
+    unwritten."""
+    return jnp.take(a, new_pos, axis=0, mode="fill", fill_value=0)[:, :n]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _cvmm_planned(x, new_pos, tile_expert, n_live, group_sizes, w, interpret,
                   tiles=None):
-    return _planned_fwd(x, new_pos, tile_expert, group_sizes, w, interpret,
-                        tiles)[0]
+    return _planned_fwd(x, new_pos, tile_expert, n_live, group_sizes, w,
+                        interpret, tiles)[0]
 
 
-def _planned_fwd(x, new_pos, tile_expert, group_sizes, w, interpret,
+def _planned_fwd(x, new_pos, tile_expert, n_live, group_sizes, w, interpret,
                  tiles=None):
     n = w.shape[2]
     m_pad = tile_expert.shape[0] * TM
     x_pad = jnp.zeros((m_pad, round_up(x.shape[1], LANE)), x.dtype)
     x_pad = x_pad.at[new_pos].set(_pad_lane(x, 1))
     out_pad = cvmm_pallas(x_pad, tile_expert, _pad_w(w), interpret=interpret,
-                          tn=None if tiles is None else tiles.fwd_tn)
+                          tn=None if tiles is None else tiles.fwd_tn,
+                          n_live=n_live)
     # Residuals carry the plan arrays AND the padded activations: backward does
     # zero layout recompute and pads only the incoming cotangent.
-    return out_pad[new_pos, :n], (x_pad, new_pos, tile_expert, group_sizes, w)
+    return _slot_rows(out_pad, new_pos, n), (x_pad, new_pos, tile_expert,
+                                             n_live, group_sizes, w)
 
 
 def _planned_bwd(interpret, tiles, res, g):
-    x_pad, new_pos, tile_expert, group_sizes, w = res
+    x_pad, new_pos, tile_expert, n_live, group_sizes, w = res
     e, k, n = w.shape
     m_pad = x_pad.shape[0]
     g_pad = jnp.zeros((m_pad, round_up(n, LANE)), g.dtype)
@@ -756,14 +782,16 @@ def _planned_bwd(interpret, tiles, res, g):
     w_pad = _pad_w(w)
     dx_pad = cvmm_pallas(g_pad, tile_expert, jnp.swapaxes(w_pad, 1, 2),
                          interpret=interpret,
-                         tn=None if tiles is None else tiles.dx_tn)
-    dx = dx_pad[new_pos, :k].astype(x_pad.dtype)
+                         tn=None if tiles is None else tiles.dx_tn,
+                         n_live=n_live)
+    dx = _slot_rows(dx_pad, new_pos, k).astype(x_pad.dtype)
     dw = cvmm_dw_pallas(x_pad, tile_expert, g_pad, e, interpret=interpret,
                         tk=None if tiles is None else tiles.dw_tk,
-                        tn=None if tiles is None else tiles.dw_tn)
+                        tn=None if tiles is None else tiles.dw_tn,
+                        n_live=n_live)
     dw = _mask_empty(dw, group_sizes)[:, :k, :n].astype(w.dtype)
-    return (dx, _float0(new_pos), _float0(tile_expert), _float0(group_sizes),
-            dw)
+    return (dx, _float0(new_pos), _float0(tile_expert), _float0(n_live),
+            _float0(group_sizes), dw)
 
 
 _cvmm_planned.defvjp(_planned_fwd, _planned_bwd)
@@ -776,8 +804,8 @@ def cvmm_planned(x: jax.Array, plan: CvmmPlan, w: jax.Array,
     derivation inside — three calls in an MoE layer share one plan). ``tiles``
     threads a pre-resolved tile decision into every launch of this call;
     omitted -> the kernels fall back to per-launch heuristic queries."""
-    return _cvmm_planned(x, plan.new_pos, plan.tile_expert, plan.group_sizes,
-                         w.astype(x.dtype), interpret, tiles)
+    return _cvmm_planned(x, plan.new_pos, plan.tile_expert, plan.n_live,
+                         plan.group_sizes, w.astype(x.dtype), interpret, tiles)
 
 
 # ---------------------------------------------------------------------------
@@ -833,12 +861,13 @@ def _fused_fwd_impl(static, xf, plan, w1, w1g, w2, save_preact=False):
         xe, plan.row_src, plan.run_start, plan.run_off, plan.tile_expert,
         _pad_w(w1), _pad_w(w1g) if w1g is not None else None,
         act_name=act_name, save_preact=save_preact, interpret=interpret,
-        tn=w1_tn, n_buffers=w1_nb)
+        tn=w1_tn, n_buffers=w1_nb, n_live=plan.n_live)
     u_pad = w1_out[0] if save_preact else w1_out
     y_pad = cvmm_fused_w2_pallas(u_pad, plan.tile_expert, _pad_w(w2),
                                  plan.gate_tiles, interpret=interpret,
-                                 tn=w2_tn)
-    # row_src slack slots hold the sentinel n — out of bounds, dropped here.
+                                 tn=w2_tn, n_live=plan.n_live)
+    # row_src slack slots hold the sentinel n — out of bounds, dropped here;
+    # so are the rows past the live bound, which the kernels leave unwritten.
     y = jnp.zeros((n, d), y_pad.dtype).at[plan.row_src].add(
         y_pad[:, :d], mode="drop")
     return y, xe, w1_out
@@ -874,16 +903,21 @@ def _fused_bwd(static, res, dy):
     m_pad = plan.m_pad
     gate = plan.gate_tiles.reshape(m_pad)[:, None]        # (M_pad, 1) f32
     runs = (plan.row_src, plan.run_start, plan.run_off, plan.tile_expert)
+    n_live = plan.n_live
 
     # Gather-free backward: the unsorted cotangent and activations stay in
     # HBM and stream through the same run-batched row-DMA plan as forward —
     # no tile-aligned (M_pad, K) copy of either is ever materialized.
+    # Every (M_pad, .) array below holds unwritten rows past the live bound.
+    # No kernel reads them (the clamped index maps stop at the bound), the
+    # dX scatter drops them (sentinel row_src) and the gate cotangent is
+    # read back only at the routed rows' slots (new_pos), all below it.
     dy_e = _pad_lane(dy, 1)
     # t0 = gather(dy) @ w2^T: the streamed fused kernel with an identity
-    # epilogue (slack rows zero-fill -> t0 slack rows are exactly zero).
+    # epilogue (slack rows of live tiles zero-fill -> exactly zero there).
     t0 = cvmm_fused_w1_pallas(dy_e, *runs, jnp.swapaxes(w2p, 1, 2), None,
                               act_name="identity", interpret=interpret,
-                              tn=t0_tn, n_buffers=t0_nb)
+                              tn=t0_tn, n_buffers=t0_nb, n_live=n_live)
     if w1g is not None:
         h, hg = preact
         u, eltwise_vjp = jax.vjp(lambda a, b: act(a) * b, h, hg)
@@ -905,24 +939,25 @@ def _fused_bwd(static, res, dy):
         cvmm_dw_streamed_pallas(u, dy_e, *runs, e, stream_x=False,
                                 gate_tiles=plan.gate_tiles,
                                 interpret=interpret, tb=dw_tb,
-                                n_buffers=dw_nb),
+                                n_buffers=dw_nb, n_live=n_live),
         plan.group_sizes)[:, :gsz, :d].astype(w2.dtype)
     dw1 = _mask_empty(
         cvmm_dw_streamed_pallas(xe, dh, *runs, e, stream_x=True,
                                 interpret=interpret, tb=dw_tb,
-                                n_buffers=dw_nb),
+                                n_buffers=dw_nb, n_live=n_live),
         plan.group_sizes)[:, :d, :gsz].astype(w1.dtype)
     dx_pad = cvmm_pallas(dh, plan.tile_expert, jnp.swapaxes(w1p, 1, 2),
-                         interpret=interpret, tn=dx_tn)
+                         interpret=interpret, tn=dx_tn, n_live=n_live)
     if w1g is not None:
         dw1g = _mask_empty(
             cvmm_dw_streamed_pallas(xe, dhg, *runs, e, stream_x=True,
                                     interpret=interpret, tb=dw_tb,
-                                    n_buffers=dw_nb),
+                                    n_buffers=dw_nb, n_live=n_live),
             plan.group_sizes)[:, :d, :gsz].astype(w1g.dtype)
         dx_pad = dx_pad + cvmm_pallas(dhg, plan.tile_expert,
                                       jnp.swapaxes(w1gp, 1, 2),
-                                      interpret=interpret, tn=dx_tn)
+                                      interpret=interpret, tn=dx_tn,
+                                      n_live=n_live)
     else:
         dw1g = None
 
@@ -933,6 +968,7 @@ def _fused_bwd(static, res, dy):
         new_pos=_float0(plan.new_pos), row_src=_float0(plan.row_src),
         run_start=_float0(plan.run_start), run_len=_float0(plan.run_len),
         run_off=_float0(plan.run_off), tile_expert=_float0(plan.tile_expert),
+        n_live=_float0(plan.n_live),
         gate_tiles=dgate.reshape(plan.gate_tiles.shape))
     return dxf, dplan, dw1, dw1g, dw2
 
@@ -985,9 +1021,9 @@ def cvmm(x: jax.Array, group_sizes: jax.Array, w: jax.Array,
         return refk.cvmm_ref(x, group_sizes, w)
     if impl in ("pallas", "pallas_interpret", "pallas_fused",
                 "pallas_fused_interpret"):
-        new_pos, tile_expert, _ = _tile_layout(group_sizes, x.shape[0],
-                                               w.shape[0])
-        return _cvmm_planned(x, new_pos, tile_expert,
+        new_pos, tile_expert, n_live, _ = _tile_layout(
+            group_sizes, x.shape[0], w.shape[0])
+        return _cvmm_planned(x, new_pos, tile_expert, n_live,
                              group_sizes.astype(jnp.int32), w.astype(x.dtype),
                              _impl_interpret(impl),
                              planned_call_tiles(x.shape[1], w.shape[2],
@@ -1174,7 +1210,10 @@ def assemble_decode_plan(plan: DecodePlan, idx: jax.Array,
     run_start, run_len, run_off = _plan_runs(row_src, plan.n_tokens)
     gate_pad = jnp.zeros((plan.m_pad,), jnp.float32).at[new_pos].set(
         g_flat[perm].astype(jnp.float32))
+    # The static expert regions leave slack between live tiles, so the live
+    # bound is the whole layout (the decode kernels launch with it too).
     return CvmmPlan(perm=perm, group_sizes=group_sizes, new_pos=new_pos,
                     row_src=row_src, run_start=run_start, run_len=run_len,
                     run_off=run_off, tile_expert=plan.tile_expert,
+                    n_live=jnp.full((1,), plan.m_pad // TM, jnp.int32),
                     gate_tiles=gate_pad.reshape(plan.m_pad // TM, TM))

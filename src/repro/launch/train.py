@@ -178,11 +178,13 @@ def run(argv=None) -> TrainRun:
                     with span("train.readback"):
                         m = {k: float(metrics[k])
                              for k in ("loss", "grad_norm", "lr",
-                                       "moe_rows_held") if k in metrics}
+                                       "moe_rows_held", "moe_tile_share")
+                             if k in metrics}
                     history.append(dict(m, step=step))
                     dt = mon.stop(step)
                     if step % args.log_every == 0 or step == args.steps - 1:
                         held = (f" rows_held {m['moe_rows_held']:.0f}"
+                                f" tile_share {m['moe_tile_share']:.3f}"
                                 if "moe_rows_held" in m else "")
                         print(f"step {step:5d} loss {m['loss']:.4f} "
                               f"lr {m['lr']:.2e} gnorm {m['grad_norm']:.3f}"

@@ -162,10 +162,13 @@ class LM:
         loss = ce + aux["moe_reg"]
         metrics = {"ce": ce, "moe_reg": aux["moe_reg"],
                    "moe_dropped": aux["moe_dropped"], "tokens": n_tok}
-        # selection-bias layers: their loads (for the step's bias update)
-        # and the rows their held experts computed
+        # selection-bias layers: their loads (for the step's bias update),
+        # the rows their held experts computed, and the share of the sort
+        # plan's row tiles that held rows (the kernels skip the rest)
         metrics.update({k: aux[k] for k in ("loads", "moe_rows_held")
                         if k in aux})
+        if "moe_tiles" in aux:
+            metrics["moe_tile_share"] = aux["moe_tiles_live"] / aux["moe_tiles"]
         return loss, (metrics if mems is None else (metrics, new_mems))
 
     def update_selection_bias(self, new_params: Dict, params: Dict,

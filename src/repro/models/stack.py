@@ -112,7 +112,7 @@ def apply_block(params: Dict, shared: Optional[Dict], x: jax.Array,
                 sp: bool = False) -> Tuple[jax.Array, Dict, Optional[Dict], Optional[jax.Array]]:
     """Pre-norm residual block. Returns (x, aux, new_cache, new_memory).
     An MoE layer with a selection bias adds its per-expert ``load`` and the
-    ``moe_rows_held`` count to aux."""
+    ``moe_rows_held``, ``moe_tiles_live`` and ``moe_tiles`` counts to aux."""
     aux = {"moe_reg": jnp.float32(0.0), "moe_dropped": jnp.float32(0.0)}
     new_cache = {}
     new_memory = None
@@ -178,7 +178,8 @@ def apply_block(params: Dict, shared: Optional[Dict], x: jax.Array,
         h = norm(fn, x)
         y, faux = apply_ffn(fp, h, _block_ffn(cfg, entry), rng=r2, train=train)
         aux = {k: aux[k] + faux.get(k, 0.0) for k in aux}
-        aux.update({k: faux[k] for k in ("load", "moe_rows_held")
+        aux.update({k: faux[k] for k in ("load", "moe_rows_held",
+                                         "moe_tiles_live", "moe_tiles")
                     if k in faux})
         x = residual(x, r2, y)
     return x, aux, (new_cache or None), new_memory
@@ -299,8 +300,9 @@ def apply_stack(params: Dict, x: jax.Array, cfg: ModelConfig, *,
                 n_layers: Optional[int] = None):
     """Run all segments. Returns (x, aux, new_cache, new_mems). Where MoE
     layers report a per-expert load, aux["loads"] holds them in the layout of
-    the stack's parameters ({"segments": [{"e<i>": (repeats, E)}]}) and
-    aux["moe_rows_held"] their held rows, summed."""
+    the stack's parameters ({"segments": [{"e<i>": (repeats, E)}]}), and
+    aux["moe_rows_held"], aux["moe_tiles_live"] and aux["moe_tiles"] their
+    held rows, live row tiles and row tiles, summed."""
     segs = plan_segments(cfg, n_layers)
     shared = params.get("shared")
     aux_tot = {"moe_reg": jnp.float32(0.0), "moe_dropped": jnp.float32(0.0)}

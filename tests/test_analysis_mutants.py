@@ -158,6 +158,31 @@ def test_plans_oracle_rejects_fetched_sentinel():
                for f in verify_plan(bad, n))
 
 
+def test_plans_oracle_rejects_a_live_bound_below_a_row():
+    # a bound one tile short: the kernels would skip a tile that holds rows
+    plan, n = _moe_plan()
+    assert verify_plan(plan, n) == []
+    bad = plan._replace(n_live=plan.n_live - 1)
+    assert any(f.check == "live-bound" for f in verify_plan(bad, n))
+
+
+def test_pipeline_flags_a_prefetch_past_the_live_bound(monkeypatch):
+    def mutant(i, m_tiles, n_buffers, *, issue, wait, when):
+        when(i == 0, lambda: issue(0))
+        for t in range(1, n_buffers - 1):
+            when((i == 0) & (t < m_tiles), lambda t=t: issue(t))
+        wait(i)
+        # drain guard dropped: the last live steps prefetch slack tiles
+        # that no step waits for
+        issue(i + n_buffers - 1)
+        return cvmm.stream_slot(i, n_buffers)
+
+    monkeypatch.setattr(cvmm, "stream_schedule_step", mutant)
+    findings, _ = check_pipeline()
+    assert any(f.check == "issue-past-live" and "n_live=" in f.location
+               for f in findings)
+
+
 def test_replay_chunk_table_matches_take():
     plan, n = _moe_plan(100, 5, 3)
     x = np.arange(n * 2, dtype=np.float32).reshape(n, 2)

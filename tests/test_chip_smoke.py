@@ -87,6 +87,14 @@ def test_xl_rel_phase_catches_a_planted_fault(smoke, monkeypatch, fault):
         smoke.xl_rel_phase(XL_REL_SMALL[:1], interpret=True)
 
 
+def test_held_share_phase_reduced(smoke):
+    n_live, grid, worst, t_live, t_all = smoke.held_share_phase(
+        n_tokens=300, d_model=128, expert_size=128, n_router=8, held=2, k=2,
+        interpret=True)
+    assert 0 < n_live < grid // 2
+    assert 0 < worst <= smoke.HELD_SHARE_RTOL
+
+
 def test_check_raises_on_failure(smoke):
     smoke.check(True, "holds")
     with pytest.raises(smoke.SmokeError):

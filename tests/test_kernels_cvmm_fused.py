@@ -3,10 +3,13 @@ gather->grouped-GEMM->epilogue kernels (interpret mode on CPU) against the
 ``ragged`` / pure-jnp oracles — forward, gradients, empty experts, E-padding,
 and the plan-reuse regression (backward must not re-derive the layout)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import cvmm, ops
 
@@ -101,6 +104,75 @@ def test_fused_gradients_match_ragged(case, glu):
                                    atol=2e-4, rtol=2e-4, err_msg=name)
 
 
+# (N_tokens, d_model, E, G, K, held_share): with held_share, 3 of 4 routed
+# rows go to experts held elsewhere (id E, gate 0, as core/moe.held_share
+# gives them): the drop_unheld plan's live bound sits far below its grid.
+# The cases share one shape, so each function below compiles once per
+# interpreter; the held-share case runs its bounded kernels in the TPU
+# interpreter that leaves unwritten memory NaN.
+LIVE_CASES = {"held_share": (300, 24, 2, 16, 2, True),
+              "all_held": (300, 24, 2, 16, 2, False)}
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _fused_vjp(idx, args, cot, all_live, interpret):
+    """The fused MLP's output and VJP under a drop_unheld plan, its live
+    bound raised to the whole grid where ``all_live``."""
+    n, e = args[0].shape[0], args[2].shape[0]
+
+    def mlp(xf, gates, w1, w1g, w2):
+        plan = ops.make_moe_plan(idx, gates, n, e, drop_unheld=True)
+        plan = plan._replace(n_live=jnp.where(
+            all_live, plan.m_pad // ops.TM, plan.n_live))
+        return ops.moe_mlp_fused(xf, plan, w1, w2, w1g, activation="gelu",
+                                 interpret=interpret)
+
+    y, vjp = jax.vjp(mlp, *args)
+    return (y,) + vjp(cot)
+
+
+@jax.jit
+def _oracle_vjp(idx, args, cot):
+    act = lambda x: jax.nn.gelu(x, approximate=True)
+    e = args[2].shape[0]
+    y, vjp = jax.vjp(lambda xf, *a: _oracle_mlp(xf, idx, *a, e, act), *args)
+    return (y,) + vjp(cot)
+
+
+@pytest.mark.parametrize("name", sorted(LIVE_CASES))
+def test_live_bound_matches_all_live(name):
+    """Kernels that stop at the plan's live bound give the forward, dX, dW1,
+    dW1g, dW2 and d(gate) of the same plan with every tile live bit for bit,
+    and the ragged oracle's within test_fused_gradients_match_ragged's
+    tolerances. With a held share the bounded kernels run in the TPU
+    interpreter that leaves unwritten memory NaN (outputs, scratch and DMA
+    targets), so nothing the kernels leave unwritten past the bound reaches
+    a result."""
+    n, d, e, g, k, share = LIVE_CASES[name]
+    interpret = (pltpu.InterpretParams(uninitialized_memory="nan") if share
+                 else True)
+    xf, idx, gates, w1, w1g, w2 = _mk((n, d, e, g, k, e), jnp.float32)
+    if share:
+        away = jax.random.bernoulli(jax.random.PRNGKey(11), 0.75, idx.shape)
+        idx, gates = jnp.where(away, e, idx), jnp.where(away, 0.0, gates)
+    gs = np.bincount(np.asarray(idx).ravel(), minlength=e + 1)[:e]
+    n_live = int(np.sum(-(-gs // ops.TM)))
+    assert n_live < ops.grid_tiles(n * k, e) // (2 if share else 1)
+    args = (xf, gates, w1, w1g, w2)
+    cot = jax.random.normal(jax.random.PRNGKey(12), xf.shape)
+    got = _fused_vjp(idx, args, cot, False, interpret)
+    every = _fused_vjp(idx, args, cot, True, True)
+    want = _oracle_vjp(idx, args, cot)
+    names = ("y", "dx", "dgates", "dw1", "dw1g", "dw2")
+    for name, a, b, c in zip(names, got, every, want):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+        tol = 1e-5 if name == "y" else 2e-4
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=tol,
+                                   rtol=tol, err_msg=name)
+
+
 def test_fused_empty_expert_weight_grads_zero():
     """Experts that receive no rows must get exactly-zero weight gradients."""
     case = (64, 32, 4, 32, 2, 3)          # expert 3 never selected
@@ -142,19 +214,27 @@ def test_fused_bf16_gradients_finite_and_close():
         np.testing.assert_allclose(a, b, atol=0.15, rtol=0.15)
 
 
-def test_plan_layout_consistency():
-    """row_src/gate_tiles/new_pos describe the same permutation."""
-    case = (100, 16, 6, 8, 3, 5)
+@pytest.mark.parametrize("drop_unheld", [False, True])
+def test_plan_layout_consistency(drop_unheld):
+    """row_src/gate_tiles/new_pos describe the same permutation, and the
+    live bound counts the tiles that hold rows. ``drop_unheld``: ids equal
+    to E are rows held elsewhere, which get no slot."""
+    case = (300, 16, 6, 8, 3, 5)
     n, d, e, g, k, _ = case
     xf, idx, gates, w1, w1g, w2 = _mk(case, jnp.float32)
-    plan = ops.make_moe_plan(idx, gates, n, e)
-    m = n * k
+    if drop_unheld:
+        idx = jnp.where(jnp.arange(n)[:, None] % 3 == 0, idx, e)
+    plan = ops.make_moe_plan(idx, gates, n, e, drop_unheld=drop_unheld)
     assert plan.row_src.shape[0] == plan.m_pad
     assert plan.m_pad % ops.TM == 0
     row_src = np.asarray(plan.row_src)
+    e_flat = np.asarray(idx.reshape(-1))
+    held = int((e_flat < e).sum())
     new_pos = np.asarray(plan.new_pos)
     tok = np.repeat(np.arange(n), k)
     perm = np.asarray(plan.perm)
+    assert (new_pos[held:] == plan.m_pad).all()     # held elsewhere: no slot
+    new_pos, perm = new_pos[:held], perm[:held]
     # every sorted row's slot points back at its source token
     assert (row_src[new_pos] == tok[perm]).all()
     # slack slots hold the sentinel and a zero gate
@@ -165,8 +245,16 @@ def test_plan_layout_consistency():
     assert (gate_pad[slack] == 0).all()
     # tiles are expert-pure: each valid slot's tile maps to its row's expert
     te = np.asarray(plan.tile_expert)
-    e_sorted = np.asarray(idx.reshape(-1))[perm]
+    e_sorted = e_flat[perm]
     assert (te[new_pos // ops.TM] == e_sorted).all()
+    # the live bound: sum(ceil(gs / TM)), every slot past it slack
+    gs = np.bincount(e_flat, minlength=e + 1)[:e]
+    n_live = int(np.sum(-(-gs // ops.TM)))
+    np.testing.assert_array_equal(plan.n_live, [n_live])
+    assert n_live == ops.live_tiles(plan.group_sizes)[0]
+    assert (row_src[n_live * ops.TM:] == n).all()
+    if drop_unheld:
+        assert 2 * n_live < plan.m_pad // ops.TM
 
 
 def test_backward_reuses_forward_plan(monkeypatch):

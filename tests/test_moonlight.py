@@ -115,6 +115,12 @@ def test_shares_add_up(ref, impl):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     # every routed row lands in exactly one share
     assert float(aux["moe_rows_held"]) > 0
+    # the last share's sort plan: a tile for each held expert with rows,
+    # of ceil(48 * k / 128) + 4 tiles
+    load = np.asarray(aux["load"])[12:16]
+    assert float(aux["moe_tiles_live"]) == np.sum(np.ceil(load / 128))
+    assert float(aux["moe_tiles"]) == -(-x.shape[0] * x.shape[1] * f.k
+                                        // 128) + 4
 
 
 def test_balance_loss_and_loads_match_reference(ref):
@@ -220,6 +226,10 @@ def test_launcher_trains_the_reduced_arch(tmp_path):
         assert np.isfinite(h["loss"])
         # 2 x 17 tokens, top-4 of 16, all held, in each of 2 MoE layers
         assert h["moe_rows_held"] == 2 * 17 * 4 * 2
+        # each layer's plan: 2 + 16 tiles, one live per expert with rows
+        # (at most 34 rows each, so 4 to 16 of them)
+        live = h["moe_tile_share"] * 2 * 18
+        assert abs(live - round(live)) < 1e-4 and 2 * 4 <= live <= 2 * 16
     bias = out.state["params"]["stack"]["segments"][1]["e0"]["ffn"][
         "router_bias"]["bias"]
     assert float(jnp.max(jnp.abs(bias))) > 0

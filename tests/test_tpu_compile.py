@@ -411,7 +411,9 @@ def test_moonlight_scopes_inside_their_layers(moonlight_step, layer, scope):
 def test_wt103_step_lowers_to_the_same_ops_for_the_chip(v5e_2x2):
     """The wt103-262m-moe step through the fused kernels, lowered for one
     chip, has the ops it had before the held-share filter, selection bias
-    and routed scale existed (tests/lowered_ops.py; kernel bodies aside)."""
+    and routed scale existed, plus the kernels' live bound: the plan's
+    ``n_live`` and its operand on each kernel call (tests/lowered_ops.py;
+    kernel bodies aside)."""
     import json
     import sys
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
